@@ -366,7 +366,8 @@ def main(argv: list[str] | None = None) -> int:
         description="cephck: project-specific static analysis "
                     "(exit 0 = clean, 1 = findings, 2 = bad config)")
     ap.add_argument("paths", nargs="*",
-                    default=["ceph_tpu", "tests", "scripts", "bench.py"],
+                    default=["ceph_tpu", "tests", "scripts", "bench.py",
+                             "chip_smoke.py"],
                     help="files/dirs to scan (default: the whole tree)")
     ap.add_argument("--baseline", default=None,
                     help=f"suppression baseline (default: "

@@ -46,11 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:
-    enable_x64 = jax.enable_x64
-except AttributeError:      # newer jax moved the scoped toggle
-    from jax.experimental import enable_x64
-
 from ._ln_tables import RH_LH_TBL, LL_TBL
 from .hashes import _mix
 from .types import (
@@ -74,9 +69,11 @@ S64_MIN = -(1 << 62)  # below any real draw (draws are > -2^49)
 U16 = 0xFFFF
 LN_BIAS = 0x1000000000000
 
-_SEED = jnp.uint32(1315423911)
-_X0 = jnp.uint32(231232)
-_Y0 = jnp.uint32(1232)
+# numpy scalars, not jnp: a jnp constant here would start the device
+# backend at import (mon/mgr processes import this module)
+_SEED = np.uint32(1315423911)
+_X0 = np.uint32(231232)
+_Y0 = np.uint32(1232)
 
 # descend outcome codes
 _HIT, _EMPTY, _BAD = 0, 1, 2
@@ -370,7 +367,7 @@ class CompiledCrushMap:
             n_class_max=self.n_class_max,
             use_classes=self.use_classes,
             first_valid=self.first_valid)
-        with enable_x64(True):
+        with jax.enable_x64(True):
             fn = _RULE_JIT.get(static)
             if fn is None:
                 def one(arrays, x, weight, static=static):
@@ -505,7 +502,7 @@ def compile_map(map_: CrushMap, choose_args=None,
             w = int(weights[p, bi, i])
             if w > 0:
                 class_of[p, bi, i] = lut[w]
-    with enable_x64(True):  # weights table must stay int64
+    with jax.enable_x64(True):  # weights table must stay int64
         return CompiledCrushMap(
             map_=map_, items=jnp.asarray(items), ids=jnp.asarray(ids),
             weights=jnp.asarray(weights), sizes=jnp.asarray(sizes),

@@ -1,9 +1,17 @@
 """Helpers to rebuild CrushMaps from fixture specs (shared by tests and the
-fixture generator).  Fixture format: see scripts/gen_crush_fixtures.py."""
+fixture generator; fixture format: see scripts/gen_crush_fixtures.py), and
+the rule x tunables hierarchy that tests and chip_smoke.py check the batch
+engine against the scalar one on."""
 from __future__ import annotations
 
-from .types import CRUSH_BUCKET_TREE, CrushBucket, CrushMap, CrushRule, \
-    CrushRuleStep
+import numpy as np
+
+from .types import (
+    CRUSH_BUCKET_STRAW2, CRUSH_BUCKET_TREE, CRUSH_RULE_CHOOSELEAF_FIRSTN,
+    CRUSH_RULE_CHOOSELEAF_INDEP, CRUSH_RULE_CHOOSE_FIRSTN,
+    CRUSH_RULE_CHOOSE_INDEP, CRUSH_RULE_EMIT, CRUSH_RULE_TAKE, CrushBucket,
+    CrushMap, CrushRule, CrushRuleStep,
+)
 
 
 def tree_node_weights(items: list[int], weights: list[int]) -> list[int]:
@@ -56,3 +64,74 @@ def map_from_spec(spec: dict) -> CrushMap:
     for steps in spec["rules"]:
         m.rules.append(CrushRule(steps=[CrushRuleStep(*s) for s in steps]))
     return m
+
+
+def build_hierarchy(n_racks=3, hosts_per_rack=3, osds_per_host=4, seed=0,
+                    tunables="jewel"):
+    """root(type 3) → racks(2) → hosts(1) → osds(0), all straw2."""
+    rng = np.random.default_rng(seed)
+    m = CrushMap()
+    m.set_tunables_profile(tunables)
+    osd = 0
+    rack_ids = []
+    for _ in range(n_racks):
+        host_ids = []
+        for _ in range(hosts_per_rack):
+            items = list(range(osd, osd + osds_per_host))
+            osd += osds_per_host
+            weights = [int(rng.integers(1, 4) * 0x10000) for _ in items]
+            hid = m.add_bucket(CrushBucket(
+                id=0, type=1, alg=CRUSH_BUCKET_STRAW2, items=items,
+                item_weights=weights, weight=sum(weights)))
+            host_ids.append(hid)
+        hw = [m.bucket(h).weight for h in host_ids]
+        rid = m.add_bucket(CrushBucket(
+            id=0, type=2, alg=CRUSH_BUCKET_STRAW2, items=host_ids,
+            item_weights=hw, weight=sum(hw)))
+        rack_ids.append(rid)
+    rw = [m.bucket(r).weight for r in rack_ids]
+    root = m.add_bucket(CrushBucket(
+        id=0, type=3, alg=CRUSH_BUCKET_STRAW2, items=rack_ids,
+        item_weights=rw, weight=sum(rw)))
+    m.max_devices = osd
+    return m, root
+
+
+RULES = {
+    "replicated_firstn": lambda root: [
+        CrushRuleStep(CRUSH_RULE_TAKE, root),
+        CrushRuleStep(CRUSH_RULE_CHOOSELEAF_FIRSTN, 3, 1),
+        CrushRuleStep(CRUSH_RULE_EMIT),
+    ],
+    "ec_indep": lambda root: [
+        CrushRuleStep(CRUSH_RULE_TAKE, root),
+        CrushRuleStep(CRUSH_RULE_CHOOSELEAF_INDEP, 0, 1),
+        CrushRuleStep(CRUSH_RULE_EMIT),
+    ],
+    "two_level_firstn": lambda root: [
+        CrushRuleStep(CRUSH_RULE_TAKE, root),
+        CrushRuleStep(CRUSH_RULE_CHOOSE_FIRSTN, 2, 2),
+        CrushRuleStep(CRUSH_RULE_CHOOSELEAF_FIRSTN, 2, 1),
+        CrushRuleStep(CRUSH_RULE_EMIT),
+    ],
+    "direct_osd_indep": lambda root: [
+        CrushRuleStep(CRUSH_RULE_TAKE, root),
+        CrushRuleStep(CRUSH_RULE_CHOOSE_INDEP, 4, 0),
+        CrushRuleStep(CRUSH_RULE_EMIT),
+    ],
+    "direct_osd_firstn": lambda root: [
+        CrushRuleStep(CRUSH_RULE_TAKE, root),
+        CrushRuleStep(CRUSH_RULE_CHOOSE_FIRSTN, 3, 0),
+        CrushRuleStep(CRUSH_RULE_EMIT),
+    ],
+}
+
+
+def make_weight(n_devices, seed=0, frac_out=0.15, frac_partial=0.15):
+    rng = np.random.default_rng(seed)
+    w = np.full(n_devices, 0x10000, dtype=np.int64)
+    rolls = rng.random(n_devices)
+    w[rolls < frac_out] = 0
+    part = (rolls >= frac_out) & (rolls < frac_out + frac_partial)
+    w[part] = rng.integers(0x1000, 0x10000, part.sum())
+    return w

@@ -92,10 +92,7 @@ class MeshECCoder:
         """shard_map step: local partial bit-counts + psum('shard')."""
         import jax
         import jax.numpy as jnp
-        try:
-            from jax import shard_map          # jax >= 0.8
-        except ImportError:                    # pragma: no cover
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def local_step(B_local, data_local):

@@ -80,16 +80,22 @@ class GFMatmul:
     def __init__(self, mat: np.ndarray, use_pallas: bool | None = None):
         self.mat = np.ascontiguousarray(mat, dtype=np.uint8)
         self.r, self.k = self.mat.shape
-        self.bitmat = jnp.asarray(
+        if use_pallas is not None:
+            self.use_pallas = use_pallas
+
+    # Both resolve on first use, not in __init__: building a code (a
+    # mon validating an EC profile) must not start the device backend.
+    @functools.cached_property
+    def bitmat(self) -> jax.Array:
+        return jnp.asarray(
             companion_bitmatrix(self.mat.tobytes(), self.r, self.k))
-        if use_pallas is None:
-            # config-selected backend; pallas only lowers on TPU.
-            # Measured on v5e (PERF_NOTES.md): the fused planar kernel
-            # beats the XLA formulation ~1.5x, so it is the default.
-            from ...common.options import global_config
-            use_pallas = (global_config()["ec_tpu_backend"] == "pallas"
-                          and jax.default_backend() == "tpu")
-        self.use_pallas = use_pallas
+
+    @functools.cached_property
+    def use_pallas(self) -> bool:
+        # config-selected backend; pallas only lowers on TPU
+        from ...common.options import global_config
+        return (global_config()["ec_tpu_backend"] == "pallas"
+                and jax.default_backend() == "tpu")
 
     def __call__(self, data) -> jax.Array:
         """data: (..., k, N) uint8 (device or host) -> (..., r, N) uint8."""
@@ -245,9 +251,9 @@ PALLAS_TILE = 8192
 # A degraded read holds the (S, n, N) chunk array in ARRIVAL layout —
 # all n = k+m slots, erased slots carrying whatever garbage happens to
 # sit there.  The staged formulation gathers k survivor rows into a
-# dense (S, k, N) array on the HOST (np.stack + moveaxis), which
-# BENCH_r05 showed costs more than the decode matmul itself
-# (decode_incl_stage 35.4 GB/s vs kernel 76.7 GB/s).  The zero-column
+# dense (S, k, N) array on the HOST (np.stack + moveaxis), which a
+# relay-era run (removed) put above the decode matmul itself; not
+# measured on the current code.  The zero-column
 # (nerrs x n) decode matrix (matrix_code.make_decode_matrix_full)
 # makes the gather unnecessary: the selection IS the matrix.  But the
 # naive full-width matmul unpacks 8n bit-planes instead of 8k — the

@@ -1292,8 +1292,7 @@ class ECBackend:
             base = rd.chunk_windows[oid][2]   # logical offset of bufs[0]
             # kernel span when the read is traced: decode_concat's
             # output is host bytes, so survivor staging (the host-side
-            # gather/stack that dominates decode_incl_stage in
-            # BENCH_r05) AND the device decode are both inside the
+            # gather/stack) AND the device decode are both inside the
             # span when it closes — and the two regions land as
             # `stage` / `kernel` CHILD spans so the split is visible
             # per op in SLO reports

@@ -154,8 +154,8 @@ def decode_concat(sinfo: StripeInfo, ec,
     "kernel": (t0, t1)} monotonic intervals separating the host-side
     survivor staging (reply buffers -> dense array layout) from the
     decode compute, so the read path's trace span can split into
-    stage/kernel children (the decode_incl_stage gap of BENCH_r05
-    made per-op visible)."""
+    stage/kernel children (the decode_incl_stage gap made per-op
+    visible)."""
     if not to_decode:
         raise ValueError("decode of no shards")
     lengths = {len(v) for v in to_decode.values()}

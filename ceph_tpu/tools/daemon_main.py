@@ -257,6 +257,11 @@ def main(argv=None) -> int:
     pd.add_argument("--crash-dir", default="",
                     help="crash-report spool dir")
     args = ap.parse_args(argv)
+    if args.role != "osd":
+        # only OSDs run device code, and a chip belongs to one
+        # process: a mon or mds must never take the host's TPU
+        import jax
+        jax.config.update("jax_platforms", "cpu")
     return {"mon": run_mon, "osd": run_osd,
             "mds": run_mds}[args.role](args)
 

@@ -34,7 +34,7 @@ cd "$(dirname "$0")/.."
 
 run_static() {
     echo "=== check_green: static analysis (cephck) ==="
-    python -m ceph_tpu.analysis ceph_tpu tests scripts bench.py
+    python -m ceph_tpu.analysis ceph_tpu tests scripts bench.py chip_smoke.py
     local rc=$?
     if [ "$rc" -ne 0 ]; then
         echo "check_green: RED (cephck rc=$rc — unsuppressed static" \

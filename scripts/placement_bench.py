@@ -160,6 +160,8 @@ def main() -> None:
     ap.add_argument("--write", action="store_true",
                     help="record PLACEMENT_BENCH.json at the repo root")
     a = ap.parse_args()
+    from ceph_tpu.common.compile_cache import use_compile_cache
+    use_compile_cache()
     out = run(a.n_osd, a.pg_num, a.sample)
     line = json.dumps(out)
     print(line)

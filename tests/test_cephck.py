@@ -329,7 +329,8 @@ def test_tree_scans_clean():
     eng = Engine([cls() for cls in ALL_RULES], ROOT,
                  suppressions=load_baseline(
                      ROOT / ".cephck-baseline.json"))
-    rc = eng.run(["ceph_tpu", "tests", "scripts", "bench.py"])
+    rc = eng.run(["ceph_tpu", "tests", "scripts", "bench.py",
+                  "chip_smoke.py"])
     assert rc == 0, "\n".join(f.render() for f in eng.findings)
     assert not eng.errors, eng.errors
     assert not eng.stale_suppressions(), [

@@ -13,87 +13,16 @@ import pytest
 
 from ceph_tpu.crush import mapper
 from ceph_tpu.crush.batch import BatchUnsupported, compile_map
-from ceph_tpu.crush.testing import map_from_spec
+from ceph_tpu.crush.testing import (RULES, build_hierarchy,
+                                    make_weight, map_from_spec)
 from ceph_tpu.crush.types import (
-    CRUSH_BUCKET_STRAW2, CRUSH_RULE_CHOOSELEAF_FIRSTN,
-    CRUSH_RULE_CHOOSELEAF_INDEP, CRUSH_RULE_CHOOSE_FIRSTN,
-    CRUSH_RULE_CHOOSE_INDEP, CRUSH_RULE_EMIT, CRUSH_RULE_TAKE, ChooseArg,
+    CRUSH_BUCKET_STRAW2, CRUSH_RULE_CHOOSELEAF_INDEP,
+    CRUSH_RULE_CHOOSE_FIRSTN, CRUSH_RULE_EMIT, CRUSH_RULE_TAKE, ChooseArg,
     CrushBucket, CrushMap, CrushRule, CrushRuleStep,
 )
 
 FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures",
                         "crush_vectors.json")
-
-
-def build_hierarchy(n_racks=3, hosts_per_rack=3, osds_per_host=4, seed=0,
-                    tunables="jewel"):
-    """root(type 3) → racks(2) → hosts(1) → osds(0), all straw2."""
-    rng = np.random.default_rng(seed)
-    m = CrushMap()
-    m.set_tunables_profile(tunables)
-    osd = 0
-    rack_ids = []
-    for _ in range(n_racks):
-        host_ids = []
-        for _ in range(hosts_per_rack):
-            items = list(range(osd, osd + osds_per_host))
-            osd += osds_per_host
-            weights = [int(rng.integers(1, 4) * 0x10000) for _ in items]
-            hid = m.add_bucket(CrushBucket(
-                id=0, type=1, alg=CRUSH_BUCKET_STRAW2, items=items,
-                item_weights=weights, weight=sum(weights)))
-            host_ids.append(hid)
-        hw = [m.bucket(h).weight for h in host_ids]
-        rid = m.add_bucket(CrushBucket(
-            id=0, type=2, alg=CRUSH_BUCKET_STRAW2, items=host_ids,
-            item_weights=hw, weight=sum(hw)))
-        rack_ids.append(rid)
-    rw = [m.bucket(r).weight for r in rack_ids]
-    root = m.add_bucket(CrushBucket(
-        id=0, type=3, alg=CRUSH_BUCKET_STRAW2, items=rack_ids,
-        item_weights=rw, weight=sum(rw)))
-    m.max_devices = osd
-    return m, root
-
-
-RULES = {
-    "replicated_firstn": lambda root: [
-        CrushRuleStep(CRUSH_RULE_TAKE, root),
-        CrushRuleStep(CRUSH_RULE_CHOOSELEAF_FIRSTN, 3, 1),
-        CrushRuleStep(CRUSH_RULE_EMIT),
-    ],
-    "ec_indep": lambda root: [
-        CrushRuleStep(CRUSH_RULE_TAKE, root),
-        CrushRuleStep(CRUSH_RULE_CHOOSELEAF_INDEP, 0, 1),
-        CrushRuleStep(CRUSH_RULE_EMIT),
-    ],
-    "two_level_firstn": lambda root: [
-        CrushRuleStep(CRUSH_RULE_TAKE, root),
-        CrushRuleStep(CRUSH_RULE_CHOOSE_FIRSTN, 2, 2),
-        CrushRuleStep(CRUSH_RULE_CHOOSELEAF_FIRSTN, 2, 1),
-        CrushRuleStep(CRUSH_RULE_EMIT),
-    ],
-    "direct_osd_indep": lambda root: [
-        CrushRuleStep(CRUSH_RULE_TAKE, root),
-        CrushRuleStep(CRUSH_RULE_CHOOSE_INDEP, 4, 0),
-        CrushRuleStep(CRUSH_RULE_EMIT),
-    ],
-    "direct_osd_firstn": lambda root: [
-        CrushRuleStep(CRUSH_RULE_TAKE, root),
-        CrushRuleStep(CRUSH_RULE_CHOOSE_FIRSTN, 3, 0),
-        CrushRuleStep(CRUSH_RULE_EMIT),
-    ],
-}
-
-
-def make_weight(n_devices, seed=0, frac_out=0.15, frac_partial=0.15):
-    rng = np.random.default_rng(seed)
-    w = np.full(n_devices, 0x10000, dtype=np.int64)
-    rolls = rng.random(n_devices)
-    w[rolls < frac_out] = 0
-    part = (rolls >= frac_out) & (rolls < frac_out + frac_partial)
-    w[part] = rng.integers(0x1000, 0x10000, part.sum())
-    return w
 
 
 def compare(m, ruleno, result_max, weight, xs):
@@ -245,10 +174,11 @@ def test_default_result_max_covers_chained_chooses():
 def test_ln16_table_matches_computed():
     """The precomputed 16-bit ln table is bit-identical to the
     arithmetic crush_ln over the whole straw2 domain."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
     from ceph_tpu.crush import batch as B
-    with B.enable_x64(True):
+    with jax.enable_x64(True):
         u = jnp.arange(65536, dtype=jnp.int64)
         want = np.asarray(B.crush_ln_vec(u))
     assert np.array_equal(B._LN16, want)

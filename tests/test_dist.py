@@ -134,3 +134,16 @@ def test_graft_entry_dryrun_inproc(devices):
     """The driver gate, run in-process on the virtual mesh."""
     import __graft_entry__ as g
     g.dryrun_multichip(8)
+
+
+def test_graft_entry_never_drops_to_cpu_on_tpu(devices, monkeypatch):
+    """On a TPU host with too few chips the dry run fails; it does not
+    start a CPU child while this process holds the chip."""
+    import jax
+
+    import __graft_entry__ as g
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(g, "_dryrun_in_subprocess", lambda n: pytest.fail(
+        "started a CPU child on a TPU host"))
+    with pytest.raises(RuntimeError, match="TPU devices present"):
+        g.dryrun_multichip(len(devices) + 1)

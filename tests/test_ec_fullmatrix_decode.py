@@ -8,7 +8,7 @@ make_decode_matrix path and the numpy oracle across EVERY erasure
 pattern (data, coding, and mixed erasures up to m) for k=8,m=4 and
 k=4,m=2, plus the singular-submatrix EIO behavior and the HBM decode-
 kernel cache bound (ref construction: ErasureCodeIsa.cc:252-306; the
-formulation this replaces is BENCH_r05's host survivor gather).
+formulation this replaces is the host survivor gather).
 """
 import itertools
 

@@ -123,7 +123,7 @@ def test_trace_context_survives_tcp_wire():
 def test_ec_decode_span_splits_into_stage_and_kernel_children():
     """The ec_decode_kernel span carries `stage` (host survivor
     gather) and `kernel` (device decode) CHILD spans, so the
-    decode_incl_stage gap BENCH_r05 exposed is visible per op in
+    decode_incl_stage gap is visible per op in
     assembled traces."""
     import sys
     sys.path.insert(0, __file__.rsplit("/", 1)[0])

@@ -1,0 +1,7 @@
+"""device: 1 - (union of device op intervals) / profiled window."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])

@@ -160,9 +160,10 @@ def _canon(msg) -> bytes:
     import dataclasses
 
     from ..msg import encoding as wire
+    # wire fields only: receiver-local state (recv_stamp) is not signed
     fields = tuple((f.name, getattr(msg, f.name))
                    for f in dataclasses.fields(msg)
-                   if f.name != "auth")
+                   if f.init and f.name != "auth")
     return wire.encode((msg.type_name, fields))
 
 

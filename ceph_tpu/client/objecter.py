@@ -127,6 +127,7 @@ class Objecter(Dispatcher, MonHunter):
         self.tracer = Tracer(self.name)
         self.ms = Messenger.create(network, self.name, threaded=threaded)
         self.ms.add_dispatcher(self)
+        self.ms.tracer = self.tracer
 
     # ------------------------------------------------------------ setup
     def start(self) -> None:

@@ -42,6 +42,16 @@ def child_of(ctx: dict | None) -> dict | None:
             "parent": ctx["span"]}
 
 
+def sibling_of(ctx: dict | None) -> dict | None:
+    """Context for a span beside the one `ctx` names: same parent, a
+    fresh id (a message's queue wait sits beside the span its handler
+    opens)."""
+    if not ctx:
+        return None
+    return {"trace_id": ctx["trace_id"], "span": _new_id(),
+            "parent": ctx.get("parent")}
+
+
 #: ambient trace context for the current thread of execution — a
 #: frontend (RGW request handler, MDS op dispatch) roots a trace and
 #: scopes it here so the layers below (objecter submit) parent their
@@ -86,11 +96,12 @@ class Span:
         self.events.append((time.monotonic() - self.start, msg))
 
     def dump(self) -> dict:
+        end = self.end or time.monotonic()
         return {"trace_id": self.trace_id, "span_id": self.span_id,
                 "parent": self.parent, "name": self.name,
                 "service": self.service,
-                "duration": round((self.end or time.monotonic())
-                                  - self.start, 6),
+                "start": self.start, "end": self.end,
+                "duration": round(end - self.start, 6),
                 "events": [{"t": round(t, 6), "event": e}
                            for t, e in self.events]}
 
@@ -108,20 +119,23 @@ class Tracer:
             return None
         return Span(ctx, name, self.service)
 
-    def finish(self, span: Span | None) -> None:
+    def finish(self, span: Span | None, end: float | None = None) -> None:
+        """Close `span` now, or at `end` (same monotonic clock) when
+        its work ended before the caller's bookkeeping."""
         if span is None:
             return
-        span.end = time.monotonic()
+        span.end = time.monotonic() if end is None else end
         with self._lock:
             self._done.append(span)
 
     def record_span(self, ctx: dict | None, name: str, start: float,
                     end: float) -> Span | None:
         """Record a span whose interval was MEASURED elsewhere (same
-        monotonic clock): sub-stage instrumentation (e.g. the EC read
-        path's survivor-stage vs kernel split) times its regions
-        inline and reports them as child spans after the fact, instead
-        of threading live Span objects through library code."""
+        monotonic clock): sub-stage instrumentation (an EC call's
+        stage/h2d/device/d2h/unstage regions, a message's wait in a
+        dispatch queue) times its regions inline and reports them as
+        spans after the fact, instead of threading live Span objects
+        through library code."""
         if not ctx:
             return None
         sp = Span(ctx, name, self.service)
@@ -163,19 +177,25 @@ def span_tree(spans: list[dict]) -> list[dict]:
 
 
 def format_tree(spans: list[dict]) -> list[str]:
-    """Indented one-span-per-line rendering of an assembled trace."""
+    """Indented one-span-per-line rendering of an assembled trace: each
+    span's offset from its root's start (one monotonic clock on a
+    host), then its duration, so queue waits and work line up."""
     lines: list[str] = []
 
-    def walk(node: dict, depth: int) -> None:
-        lines.append("{}{} [{}] {:.6f}s".format(
-            "  " * depth, node["name"], node["service"],
+    def walk(node: dict, depth: int, t0: float | None) -> None:
+        start = node.get("start")
+        at = "" if start is None or t0 is None \
+            else "+{:.6f}s ".format(start - t0)
+        lines.append("{}{} [{}] {}{:.6f}s".format(
+            "  " * depth, node["name"], node["service"], at,
             node["duration"]))
         for ev in node.get("events", []):
             lines.append("{}  @{:.6f} {}".format(
                 "  " * depth, ev["t"], ev["event"]))
-        for child in node["children"]:
-            walk(child, depth + 1)
+        for child in sorted(node["children"],
+                            key=lambda n: n.get("start") or 0.0):
+            walk(child, depth + 1, t0)
 
     for root in span_tree(spans):
-        walk(root, 0)
+        walk(root, 0, root.get("start"))
     return lines

@@ -15,6 +15,7 @@ from __future__ import annotations
 import itertools
 import queue
 import threading
+import time
 from collections import deque
 
 from ..common.lockdep import make_lock
@@ -24,6 +25,7 @@ from typing import Callable, Optional
 from ..common.log import dout
 from ..common.options import global_config
 from ..common.racecheck import shared_state
+from ..common.tracing import sibling_of
 
 EntityName = str      # "osd.3", "mon.0", "client.4121"
 
@@ -45,6 +47,11 @@ class Message:
     # blkin-style trace context riding the message
     # (ref: Message.h:263 ZTracer::Trace trace)
     trace: Optional[dict] = field(default=None, compare=False)
+    # span-clock (time.monotonic) time the receiving messenger queued
+    # this message (ref: Message::get_recv_stamp).  Receiver-local:
+    # not an init field, so never encoded, signed or copied by replace
+    recv_stamp: Optional[float] = field(default=None, init=False,
+                                        compare=False, repr=False)
 
     @property
     def type_name(self) -> str:
@@ -96,6 +103,9 @@ class Messenger:
         # blows up on the dispatch thread (the daemon's CrashReporter;
         # ref: the global handle_fatal_signal crash dump path)
         self.crash_hook = None
+        #: the owning daemon's Tracer: each traced message's wait in
+        #: the dispatch queue lands there as `ms_queue:<type>`
+        self.tracer = None
 
     # -- factory (ref: Messenger.cc:21 Messenger::create) ---------------
     @staticmethod
@@ -159,6 +169,7 @@ class Messenger:
 
     def enqueue(self, msg: Message) -> None:
         """Queued for the dispatch thread (threaded) or until poll()."""
+        msg.recv_stamp = time.monotonic()
         self._queue.put(msg)
 
     def poll(self, max_msgs: int = 0) -> int:
@@ -203,6 +214,12 @@ class Messenger:
                 "%s: dropping unauthenticated %s from %s", self.name,
                 msg.type_name, msg.src)
             return
+        if self.tracer is not None and msg.trace and \
+                msg.recv_stamp is not None:
+            # the wait in this queue, beside the handler's own span
+            self.tracer.record_span(sibling_of(msg.trace),
+                                    "ms_queue:" + msg.type_name,
+                                    msg.recv_stamp, time.monotonic())
         for d in self.dispatchers:
             if d.ms_dispatch(msg):
                 return

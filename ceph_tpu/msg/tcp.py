@@ -146,6 +146,9 @@ class TcpMessenger:
         self.auth_verifier = None
         # crash capture (same surface as the in-process messenger)
         self.crash_hook = None
+        # same surface as the in-process messenger; reader threads
+        # dispatch inline, so there is no queue wait to record
+        self.tracer = None
 
     # -- messenger surface ----------------------------------------------
     def add_dispatcher(self, d: Dispatcher) -> None:

@@ -15,6 +15,7 @@ glue.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Optional
 
 from ..common.heartbeat_map import HeartbeatMap
@@ -265,14 +266,16 @@ class OSDDaemon(Dispatcher, MonHunter):
         self.perf = coll.create(self.name)
         for key in ("op", "op_r", "op_w", "op_r_bytes", "op_w_bytes",
                     "subop_w", "recovery_push", "recovery_pull",
-                    "recovery_bytes_read", "recovery_bytes_rebuilt",
-                    "map_epochs"):
+                    "map_epochs") + ECBackend.PERF_KEYS:
             self.perf.add_u64_counter(key)
         # per-op-class latency histograms (ref: the l_osd_op_*_lat
         # family + mClock op classes): exported by mgr/prometheus as
         # real histogram families (_bucket/_sum/_count)
         for key in ("op_lat_client", "op_lat_recovery",
-                    "op_lat_snaptrim"):
+                    "op_lat_snaptrim",
+                    # a client op's wait from the messenger's queue to
+                    # dispatch (ref: OSD.cc l_osd_op_before_dequeue_op_lat)
+                    "op_before_dequeue_op_lat"):
             self.perf.add_latency_histogram(key)
         # messenger drops seen by the shared network fabric
         # (FaultPlane/filter/shim): a monotonic total so chaos runs
@@ -291,6 +294,7 @@ class OSDDaemon(Dispatcher, MonHunter):
         self.crash = CrashReporter(self.name, crash_dir=crash_dir,
                                    post=self._post_crash_meta)
         self.ms.crash_hook = self.crash.capture
+        self.ms.tracer = self.tracer
         #: fault hook: raise out of the next heartbeat tick (the
         #: osd_debug_inject_crash_tick analogue, settable per-daemon)
         self.inject_crash_tick = \
@@ -396,6 +400,9 @@ class OSDDaemon(Dispatcher, MonHunter):
             self.crash.on_ack(msg.tid, msg.result)
             return True
         if isinstance(msg, OSDOp):
+            if msg.recv_stamp is not None:
+                self.perf.hobs("op_before_dequeue_op_lat",
+                               time.monotonic() - msg.recv_stamp)
             self.op_tracker.start(
                 (msg.src, msg.tid),
                 f"osd_op({msg.src} tid={msg.tid} {msg.op} "
@@ -445,7 +452,8 @@ class OSDDaemon(Dispatcher, MonHunter):
                     # instead of waiting on an ack that never comes
                     reply = ECSubWriteReply(pgid=msg.pgid, tid=msg.tid,
                                             shard=msg.shard,
-                                            committed=False)
+                                            committed=False,
+                                            trace=msg.trace)
             self.ms.connect(msg.src).send_message(reply)
             return True
         if isinstance(msg, ECSubRead):
@@ -469,6 +477,7 @@ class OSDDaemon(Dispatcher, MonHunter):
                 # reading primary fails fast instead of waiting
                 reply = ECSubReadReply(
                     pgid=msg.pgid, tid=msg.tid, shard=msg.shard,
+                    trace=msg.trace,
                     errors={**{oid: "ESTALE"
                                for oid, _off, _len in msg.to_read},
                             **{oid: "ESTALE"
@@ -2429,7 +2438,8 @@ class OSDDaemon(Dispatcher, MonHunter):
             self.tracer.finish(sp)
         self.ms.connect(msg.src).send_message(OSDOpReply(
             tid=msg.tid, result=result, errno_name=errno_name,
-            data=data, attrs=attrs or {}, epoch=self.osdmap.epoch))
+            data=data, attrs=attrs or {}, epoch=self.osdmap.epoch,
+            trace=msg.trace))
 
     def _handle_client_op(self, msg: OSDOp) -> None:
         st = self.pgs.get(msg.pgid)

@@ -16,6 +16,8 @@ Three pieces (ref: src/osd/ECUtil.{h,cc}):
 """
 from __future__ import annotations
 
+import contextlib
+import time
 from typing import Iterable, Mapping
 
 import numpy as np
@@ -81,20 +83,71 @@ def _identity_mapping(ec) -> bool:
     return not mapping or mapping == list(range(len(mapping)))
 
 
-def _batchable(ec) -> bool:
+def batchable(ec) -> bool:
+    """Whether encode/decode take the one-dispatch device path."""
     return (hasattr(ec, "encode_batch") and _identity_mapping(ec)
             and ec.get_sub_chunk_count() == 1)
 
 
+#: the regions of one timed EC call, in order.  On the batched path:
+#: stage (host gather into the dispatch layout), h2d (jax.device_put
+#: until ready), device (dispatch until ready), d2h (np.asarray),
+#: unstage (per-shard tobytes, decode_concat's stack).  The per-stripe
+#: path of host-native plugins has no device: it reports one `kernel`.
+STAGES = ("stage", "h2d", "device", "d2h", "unstage", "kernel")
+
+
+class _Stages:
+    """Times the consecutive regions of one EC call into `timings`.
+
+    With `timings` None every method does nothing, so an untimed call
+    issues the same transfers and dispatches in the same order; a timed
+    one adds only the waits that end its h2d and device regions.  A
+    caller may put a "trace_id" into `timings`: the device region then
+    also runs inside a profiler annotation `ec_device` carrying it, so
+    a device trace shows each call's dispatch beside the device ops it
+    launched, and the same interval on both clocks."""
+
+    __slots__ = ("timings", "last")
+
+    def __init__(self, timings: dict | None):
+        self.timings = timings
+        self.last = time.monotonic() if timings is not None else 0.0
+
+    def mark(self, name: str, ready=None) -> None:
+        """Close region `name` now (after `ready` is ready)."""
+        if self.timings is None:
+            return
+        if ready is not None:
+            import jax
+            jax.block_until_ready(ready)
+        now = time.monotonic()
+        self.timings[name] = (self.last, now)
+        self.last = now
+
+    @contextlib.contextmanager
+    def device(self):
+        if self.timings is None:
+            yield
+            return
+        import jax
+        with jax.profiler.TraceAnnotation(
+                "ec_device", trace_id=self.timings.get("trace_id", "")):
+            self.last = time.monotonic()
+            yield
+
+
 def encode(sinfo: StripeInfo, ec, data: bytes,
-           want: Iterable[int] | None = None) -> dict[int, bytes]:
+           want: Iterable[int] | None = None,
+           timings: dict | None = None) -> dict[int, bytes]:
     """Encode a stripe-aligned logical buffer into per-shard chunk
     streams (ref: ECUtil.cc:120-159).
 
     Returns {shard: bytes} where each shard's buffer is the
     concatenation of that shard's chunk from every stripe.  One batched
     device dispatch for matrix plugins; per-stripe plugin.encode
-    otherwise.
+    otherwise.  `timings`, when passed, receives a monotonic interval
+    per region of STAGES the call ran (see _Stages).
     """
     k = ec.get_data_chunk_count()
     m = ec.get_coding_chunk_count()
@@ -109,14 +162,22 @@ def encode(sinfo: StripeInfo, ec, data: bytes,
     nstripes = len(data) // sinfo.stripe_width
     cs = sinfo.chunk_size
 
-    if _batchable(ec):
+    if batchable(ec):
+        import jax
+        st = _Stages(timings)
         arr = np.frombuffer(data, dtype=np.uint8).reshape(nstripes, k, cs)
+        st.mark("stage")
         # the one legal host->device crossing of the encode path is
-        # the plugin's explicit staging; under CEPH_TPU_JAXGUARD any
-        # IMPLICIT transfer inside the dispatch is an error
-        with jaxguard.guard_transfers():
-            parity_dev = ec.encode_batch(arr)
+        # this explicit staging; under CEPH_TPU_JAXGUARD any IMPLICIT
+        # transfer inside the dispatch is an error
+        dev = jax.device_put(arr)
+        st.mark("h2d", dev)
+        with st.device():
+            with jaxguard.guard_transfers():
+                parity_dev = ec.encode_batch(dev)
+            st.mark("device", parity_dev)
         parity = np.asarray(parity_dev)                 # (S, m, cs)
+        st.mark("d2h")
         out: dict[int, bytes] = {}
         # tobytes() emits C-order bytes from a strided view directly —
         # an ascontiguousarray here would copy each shard slice twice
@@ -125,10 +186,12 @@ def encode(sinfo: StripeInfo, ec, data: bytes,
                 out[shard] = arr[:, shard, :].tobytes()
             else:
                 out[shard] = parity[:, shard - k, :].tobytes()
+        st.mark("unstage")
         return out
 
     # general path: per-stripe plugin encode (handles chunk remapping
     # and sub-chunk plugins)
+    t0 = time.monotonic()
     parts: dict[int, list] = {i: [] for i in want}
     for s in range(nstripes):
         stripe = data[s * sinfo.stripe_width:(s + 1) * sinfo.stripe_width]
@@ -141,7 +204,10 @@ def encode(sinfo: StripeInfo, ec, data: bytes,
             # path above, so no device boundary is crossed here
             # cephck: ignore[host-sync-hot-path] — host-native plugin path
             parts[i].append(np.asarray(chunk, dtype=np.uint8))
-    return {i: np.concatenate(parts[i]).tobytes() for i in want}
+    out = {i: np.concatenate(parts[i]).tobytes() for i in want}
+    if timings is not None:
+        timings["kernel"] = (t0, time.monotonic())
+    return out
 
 
 def decode_concat(sinfo: StripeInfo, ec,
@@ -150,12 +216,11 @@ def decode_concat(sinfo: StripeInfo, ec,
     """Rebuild the logical stream from >=k shard chunk streams
     (ref: ECUtil.cc:9 decode -> decode_concat per stripe).
 
-    `timings`, when passed, receives {"stage": (t0, t1),
-    "kernel": (t0, t1)} monotonic intervals separating the host-side
-    survivor staging (reply buffers -> dense array layout) from the
-    decode compute, so the read path's trace span can split into
-    stage/kernel children (the decode_incl_stage gap made per-op
-    visible)."""
+    `timings`, when passed, receives a monotonic interval per region
+    of STAGES the call ran (see _Stages): the host survivor staging,
+    both transfers and the device decode when shards are missing, and
+    `unstage` for the per-shard tobytes plus the stack into the
+    logical stream (a read with every data shard runs only that)."""
     if not to_decode:
         raise ValueError("decode of no shards")
     lengths = {len(v) for v in to_decode.values()}
@@ -170,19 +235,25 @@ def decode_concat(sinfo: StripeInfo, ec,
     nstripes = total // sinfo.chunk_size
     cs = sinfo.chunk_size
 
-    if _batchable(ec):
+    if batchable(ec):
         # identity mapping: shards 0..k-1 ARE the data chunks
         out = decode(sinfo, ec, to_decode, want=range(k),
                      timings=timings)
+        t0 = time.monotonic() if timings is not None else 0.0
         arrs = [np.frombuffer(out[i], dtype=np.uint8).reshape(nstripes, cs)
                 for i in range(k)]
-        return np.ascontiguousarray(
+        logical = np.ascontiguousarray(
             np.stack(arrs, axis=1)).tobytes()  # (S, k, cs) -> logical
+        if timings is not None:
+            # one unstage region: decode's tobytes (if it decoded) and
+            # this stack
+            timings["unstage"] = (timings.get("unstage", (t0,))[0],
+                                  time.monotonic())
+        return logical
 
     # general path: the plugin's decode_concat knows the chunk mapping
     # (ref: ECUtil.cc:31 per-stripe ec_impl->decode_concat)
-    import time as _time
-    t0 = _time.monotonic()
+    t0 = time.monotonic()
     views = {i: np.frombuffer(v, dtype=np.uint8)
              for i, v in to_decode.items()}
     parts = []
@@ -192,7 +263,7 @@ def decode_concat(sinfo: StripeInfo, ec,
         assert len(stripe) == sinfo.stripe_width
         parts.append(stripe)
     if timings is not None:       # per-stripe path: no separate stage
-        timings["kernel"] = (t0, _time.monotonic())
+        timings["kernel"] = (t0, time.monotonic())
     return b"".join(parts)
 
 
@@ -204,7 +275,7 @@ def decode(sinfo: StripeInfo, ec, to_decode: Mapping[int, bytes],
 
     Batched: a single device dispatch reconstructs every stripe's
     missing chunks for matrix plugins.  `timings` (optional dict)
-    receives "stage"/"kernel" monotonic intervals — see decode_concat.
+    receives a monotonic interval per region of STAGES it ran.
     """
     want = sorted(set(want))
     avail = sorted(to_decode)
@@ -229,34 +300,33 @@ def decode(sinfo: StripeInfo, ec, to_decode: Mapping[int, bytes],
     if not missing:
         return out
 
-    if _batchable(ec) and len(avail) >= k:
-        import time as _time
+    if batchable(ec) and len(avail) >= k:
+        import jax
+        st = _Stages(timings)
         decode_index = avail[:k]
-        t0 = _time.monotonic()
         stack = np.stack(
             [np.frombuffer(to_decode[i], dtype=np.uint8)
              .reshape(nstripes, cs) for i in decode_index], axis=1)
-        t1 = _time.monotonic()
-        # np.asarray forces the device dispatch (D2H sync), so the
-        # kernel interval below is compute + readback, never
-        # dispatch-only; the guard makes any implicit transfer inside
-        # the dispatch an error under CEPH_TPU_JAXGUARD
-        with jaxguard.guard_transfers():
-            rec_dev = ec.decode_batch(decode_index, missing, stack)
+        st.mark("stage")
+        dev = jax.device_put(stack)
+        st.mark("h2d", dev)
+        # the guard makes any implicit transfer inside the dispatch an
+        # error under CEPH_TPU_JAXGUARD
+        with st.device():
+            with jaxguard.guard_transfers():
+                rec_dev = ec.decode_batch(decode_index, missing, dev)
+            st.mark("device", rec_dev)
         rec = np.asarray(rec_dev)
-        t2 = _time.monotonic()
-        if timings is not None:
-            timings["stage"] = (t0, t1)
-            timings["kernel"] = (t1, t2)
+        st.mark("d2h")
         for pos, i in enumerate(missing):
             # tobytes() handles the strided view; rec was synced once
             # above, so this loop is host memcpy only
             out[i] = rec[:, pos, :].tobytes()
+        st.mark("unstage")
         return out
 
     # general path: per-stripe plugin decode
-    import time as _time
-    t0 = _time.monotonic()
+    t0 = time.monotonic()
     parts: dict[int, list] = {i: [] for i in missing}
     for s in range(nstripes):
         chunks = {i: np.frombuffer(v, dtype=np.uint8)[s * cs:(s + 1) * cs]
@@ -270,7 +340,7 @@ def decode(sinfo: StripeInfo, ec, to_decode: Mapping[int, bytes],
     for i in missing:
         out[i] = np.concatenate(parts[i]).tobytes()
     if timings is not None:       # per-stripe path: no separate stage
-        timings["kernel"] = (t0, _time.monotonic())
+        timings["kernel"] = (t0, time.monotonic())
     return out
 
 

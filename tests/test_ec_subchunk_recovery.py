@@ -19,7 +19,7 @@ sys.path.insert(0, __file__.rsplit("/", 1)[0])
 from ceph_tpu.common.perf_counters import PerfCounters
 from ceph_tpu.msg.messages import ECSubRead
 from ceph_tpu.osd import ecutil
-from ceph_tpu.osd.ec_backend import pg_cid
+from ceph_tpu.osd.ec_backend import ECBackend, pg_cid
 from ceph_tpu.store import ObjectId
 
 from test_ec_backend import Cluster, _payload
@@ -29,7 +29,7 @@ PGID = "1.0"
 
 def _perf():
     p = PerfCounters("t")
-    for key in ("recovery_bytes_read", "recovery_bytes_rebuilt"):
+    for key in ECBackend.PERF_KEYS:
         p.add_u64_counter(key)
     return p
 

@@ -419,6 +419,10 @@ def test_cross_zone_delete_beats_racing_put(ms):
     # straight past the del record the test depends on
     req(m1, "PUT", "/tdrace/warm", b"w")
     assert _wait(lambda: _get_bytes(m2, "tdrace", "warm") == b"w")
+    # the warm delete itself needs m1 past its full sync of m2's
+    # tdrace: full sync copies what the dump holds and never deletes,
+    # so a delete that lands before m1's dump is skipped for good
+    assert _wait(lambda: "tdrace" in m1.sync.markers_for(m2.sync.zone))
     req(m2, "DELETE", "/tdrace/warm")
     assert _wait(lambda: _get_bytes(m1, "tdrace", "warm") is None)
     # stall m1's OUTBOUND pulls: m2's delete stays unseen at m1 while

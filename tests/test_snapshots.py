@@ -199,7 +199,16 @@ def test_clones_survive_recovery(cluster, io):
     assert moved, "newcomer never received the clones"
     assert io.read(oid, snapid=sid) == b"snapshotted data"
     assert io.read(oid) == b"newer data"
+    e1 = r.objecter.osdmap.epoch
     r.mon_command({"prefix": "osd in", "ids": [victim]})
+    r.objecter.wait_for_map(e1 + 1)
+    # the module's cluster is shared: let every OSD take the map and
+    # finish moving the PGs back before the next test touches them
+    deadline = time.monotonic() + 20
+    while any(d.osdmap.epoch < r.objecter.osdmap.epoch
+              or d.pgs_recovering() for d in c.osds.values()):
+        assert time.monotonic() < deadline, "PGs never settled"
+        time.sleep(0.1)
 
 
 def test_scrub_detects_clone_divergence(cluster, io):

@@ -1,6 +1,7 @@
 """Distributed tracing: blkin-style spans across client -> primary ->
 replicas/shards (ref: src/common/zipkin_trace.h, Message.h:263,
 OpRequest::pg_trace into ECBackend.cc:1508)."""
+import numpy as np
 import pytest
 
 from ceph_tpu.common.options import global_config
@@ -14,6 +15,9 @@ def test_span_primitives():
     assert child["trace_id"] == root["trace_id"]
     assert child["parent"] == root["span"]
     assert child_of(None) is None
+    ids = {child_of(root)["span"] for _ in range(10000)}
+    assert len(ids) == 10000
+    assert all(len(i) == 16 and int(i, 16) >= 0 for i in ids)
     t = Tracer("osd.0", keep=2)
     assert t.start_span(None, "x") is None     # tracing off: no-op
     for i in range(3):
@@ -120,20 +124,32 @@ def test_trace_context_survives_tcp_wire():
         wire.encode_message(OSDOp(oid="o"))).trace is None
 
 
-def test_ec_decode_span_splits_into_stage_and_kernel_children():
-    """The ec_decode_kernel span carries `stage` (host survivor
-    gather) and `kernel` (device decode) CHILD spans, so the
-    decode_incl_stage gap is visible per op in
-    assembled traces."""
+#: the timed regions of one EC call on the batched (device) path
+EC_STAGES = ["d2h", "device", "h2d", "stage", "unstage"]
+
+
+def _ec_backend_cluster():
     import sys
     sys.path.insert(0, __file__.rsplit("/", 1)[0])
     from test_ec_backend import Cluster, _payload
+    cl = Cluster()
+    cl.backend.tracer = Tracer("osd.0")
+    return cl, _payload(4 * cl.backend.sinfo.stripe_width)
+
+
+def _children(spans, parent):
+    return [s for s in spans if s["parent"] == parent["span_id"]]
+
+
+def test_ec_decode_span_splits_into_stage_and_kernel_children():
+    """The ec_decode_kernel span carries `stage` (host survivor
+    gather), `h2d`, `device`, `d2h` and `unstage` CHILD spans, so host
+    staging, transfers and the device decode are visible per op in
+    assembled traces."""
     from ceph_tpu.common.tracing import span_tree
 
-    cl = Cluster()
-    tracer = Tracer("osd.0")
-    cl.backend.tracer = tracer
-    data = _payload(2 * cl.backend.sinfo.stripe_width)
+    cl, data = _ec_backend_cluster()
+    tracer = cl.backend.tracer
     assert cl.write("obj", 0, data)
     cl.kill(1)          # degraded read: reconstruction must run
     out = {}
@@ -145,12 +161,311 @@ def test_ec_decode_span_splits_into_stage_and_kernel_children():
     spans = tracer.dump()
     parents = [s for s in spans if s["name"] == "ec_decode_kernel"]
     assert len(parents) == 1
-    kids = [s for s in spans if s["parent"] == parents[0]["span_id"]]
-    names = sorted(k["name"] for k in kids)
-    assert names == ["kernel", "stage"]
+    kids = _children(spans, parents[0])
+    assert sorted(k["name"] for k in kids) == EC_STAGES
     for k in kids:
         assert 0 <= k["duration"] <= parents[0]["duration"] + 1e-6
     # the tree renders with the children nested under the decode span
     tree = span_tree(spans)
     node = [n for n in tree if n["name"] == "ec_decode_kernel"]
-    assert node and len(node[0]["children"]) == 2
+    assert node and len(node[0]["children"]) == len(EC_STAGES)
+
+
+@pytest.mark.parametrize("call", ["ec_encode_kernel", "ec_decode_kernel"])
+def test_ec_call_stages_cover_the_call(call):
+    """The five regions of a traced encode or degraded decode follow
+    one another inside the call's span and cover at least 90 % of it
+    (host staging, both transfers, the device work, unstaging)."""
+    cl, data = _ec_backend_cluster()
+    tracer = cl.backend.tracer
+    for rnd in range(2):          # the first round compiles
+        oid = f"obj{rnd}"
+        done = {}
+        cl.backend.submit_transaction(
+            oid, [("write", 0, data)], lambda ok: done.update(ok=ok),
+            trace=new_trace())
+        assert done["ok"]
+    cl.kill(1)
+    for rnd in range(2):
+        out = {}
+        cl.backend.objects_read_and_reconstruct(
+            {f"obj{rnd}": (0, 0)},
+            lambda r, e: out.update(results=r), trace=new_trace())
+        assert out["results"][f"obj{rnd}"] == data
+    spans = tracer.dump()
+    last = [s for s in spans if s["name"] == call][-1]
+    kids = sorted(_children(spans, last), key=lambda s: s["start"])
+    assert sorted(k["name"] for k in kids) == EC_STAGES
+    assert [k["name"] for k in kids] == \
+        ["stage", "h2d", "device", "d2h", "unstage"]
+    for a, b in zip(kids, kids[1:]):
+        assert a["end"] <= b["start"] + 1e-9
+    assert last["start"] <= kids[0]["start"]
+    assert kids[-1]["end"] <= last["end"]
+    covered = sum(k["end"] - k["start"] for k in kids)
+    assert covered >= 0.9 * (last["end"] - last["start"])
+
+
+def test_ec_stage_timings_leave_the_bytes_unchanged():
+    """ecutil with and without `timings`: the same shards and the same
+    decoded stream; the timed call fills every region."""
+    from ceph_tpu.ec import registry
+    from ceph_tpu.osd import ecutil
+    rng = np.random.default_rng(7)
+    ec = registry.factory("tpu", {"k": "4", "m": "2"})
+    sinfo = ecutil.StripeInfo(4, 4 * 4096)
+    data = rng.bytes(3 * sinfo.stripe_width)
+    plain = ecutil.encode(sinfo, ec, data)
+    t_enc: dict = {}
+    assert ecutil.encode(sinfo, ec, data, timings=t_enc) == plain
+    survivors = {i: plain[i] for i in (0, 2, 3, 5)}
+    t_dec: dict = {}
+    assert ecutil.decode_concat(sinfo, ec, survivors) == data
+    assert ecutil.decode_concat(sinfo, ec, survivors,
+                                timings=t_dec) == data
+    for t in (t_enc, t_dec):
+        assert sorted(t) == EC_STAGES
+        assert all(a <= b for a, b in t.values())
+
+
+def test_ec_backend_counts_dispatches_and_transfer_bytes():
+    """Each encode and each decode that reached the device counts one
+    dispatch and its bytes each way, traced or not; a read with every
+    data shard dispatches nothing."""
+    from ceph_tpu.common.perf_counters import PerfCounters
+    from ceph_tpu.osd.ec_backend import ECBackend
+    cl, data = _ec_backend_cluster()
+    cl.backend.tracer = None
+    perf = PerfCounters("t")
+    for key in ECBackend.PERF_KEYS:
+        perf.add_u64_counter(key)
+    cl.backend.perf = perf
+
+    def counts():
+        return [perf.get(k) for k in
+                ("ec_dispatches", "ec_h2d_bytes", "ec_d2h_bytes")]
+
+    k, m = cl.k, cl.m
+    assert cl.write("obj", 0, data)
+    assert counts() == [1, len(data), len(data) // k * m]
+    assert cl.read("obj") == data
+    assert counts() == [1, len(data), len(data) // k * m]
+    cl.kill(1)
+    assert cl.read("obj") == data
+    shard = len(data) // k
+    assert counts() == [2, len(data) + k * shard,
+                        len(data) // k * m + shard]
+
+
+def _ec_cluster_run():
+    """Untraced then traced EC writes of one payload, an OSD holding a
+    data shard killed, then an untraced and a traced degraded read,
+    on a threaded MiniCluster (k=2, m=2)."""
+    cfg = global_config()
+    c = MiniCluster(n_osd=5, threaded=True)
+    run: dict = {}
+    try:
+        c.wait_all_up()
+        r = c.rados()
+        r.mon_command({"prefix": "osd erasure-code-profile set",
+                       "name": "k2m2",
+                       "profile": {"plugin": "tpu", "k": "2", "m": "2",
+                                   "crush-failure-domain": "osd"}})
+        r.pool_create("tp", pg_num=8, pool_type="erasure",
+                      erasure_code_profile="k2m2")
+        io = r.open_ioctx("tp")
+        payload = bytes(range(256)) * 96
+        run["payload"] = payload
+
+        def tracers():
+            return [r.objecter.tracer] + \
+                [d.tracer for d in c.osds.values()]
+
+        def all_spans():
+            return [s for t in tracers() for s in t.dump()]
+
+        def shards(name):
+            out = {}
+            for d in c.osds.values():
+                for cid in d.store.list_collections():
+                    for oid in d.store.collection_list(cid):
+                        if oid.name == name and oid.shard >= 0 and \
+                                oid.snap == -2:
+                            out[oid.shard] = d.store.read(cid, oid)
+            return out
+
+        io.write_full("plain", payload)
+        run["untraced_write_spans"] = all_spans()
+        cfg.set("blkin_trace_all", True)
+        try:
+            io.write_full("traced", payload)
+        finally:
+            cfg.set("blkin_trace_all", False)
+        run["shards"] = (shards("plain"), shards("traced"))
+        pid = r.pool_lookup("tp")
+        omap = r.objecter.osdmap
+        acting = omap.pg_to_up_acting_osds(
+            omap.object_locator_to_pg("traced", pid))[2]
+        victim = acting[1]               # data shard 1, not the primary
+        c.kill_osd(victim)
+        r.mon_command({"prefix": "osd down", "ids": [str(victim)]})
+        import time
+        end = time.monotonic() + 30
+        while r.objecter.osdmap.is_up(victim):
+            assert time.monotonic() < end, "victim never marked down"
+            time.sleep(0.05)
+        before = len(all_spans())
+        run["untraced_read"] = io.read("traced")
+        run["untraced_read_new_spans"] = len(all_spans()) - before
+        cfg.set("blkin_trace_all", True)
+        try:
+            run["traced_read"] = io.read("traced")
+        finally:
+            cfg.set("blkin_trace_all", False)
+        run["spans"] = all_spans()
+    finally:
+        cfg.set("blkin_trace_all", False)
+        c.shutdown()
+    return run
+
+
+@pytest.fixture(scope="module")
+def ec_cluster_run():
+    return _ec_cluster_run()
+
+
+def _op_roots(spans, op):
+    return [s for s in spans if s["name"] == f"objecter_op:{op}"]
+
+
+#: (queue span, the op it belongs to, the span it sits under)
+QUEUE_SPANS = [
+    ("ms_queue:OSDOp", "write_full", "objecter_op"),
+    ("ms_queue:OSDOpReply", "write_full", "objecter_op"),
+    ("ms_queue:ECSubWrite", "write_full", "osd_op"),
+    ("ms_queue:ECSubWriteReply", "write_full", "osd_op"),
+    ("ms_queue:OSDOp", "read", "objecter_op"),
+    ("ms_queue:OSDOpReply", "read", "objecter_op"),
+    ("ms_queue:ECSubRead", "read", "osd_op"),
+    ("ms_queue:ECSubReadReply", "read", "osd_op"),
+]
+
+
+@pytest.mark.parametrize("name,op,under", QUEUE_SPANS)
+def test_queue_span_sits_under_its_parent(ec_cluster_run, name, op,
+                                          under):
+    """Each messenger hop of a traced EC write and degraded read leaves
+    a `ms_queue:<type>` span, beside the handler's span: under the
+    client's objecter_op for the client's messages, under the
+    primary's osd_op for the sub-ops and their replies, and inside
+    that parent's interval."""
+    spans = ec_cluster_run["spans"]
+    roots = _op_roots(spans, op)
+    assert len(roots) == 1
+    mine = [s for s in spans if s["trace_id"] == roots[0]["trace_id"]]
+    by_id = {s["span_id"]: s for s in mine}
+    found = [s for s in mine if s["name"] == name]
+    assert found
+    for q in found:
+        parent = by_id[q["parent"]]
+        assert parent["name"].startswith(under)
+        assert parent["start"] <= q["start"] <= q["end"] <= parent["end"]
+
+
+def test_traced_cluster_ops_carry_ec_stage_children(ec_cluster_run):
+    """On the cluster too, the traced write's encode and the degraded
+    read's decode carry the five regions."""
+    spans = ec_cluster_run["spans"]
+    for call in ("ec_encode_kernel", "ec_decode_kernel"):
+        calls = [s for s in spans if s["name"] == call]
+        assert len(calls) == 1
+        assert sorted(k["name"] for k in _children(spans, calls[0])) \
+            == EC_STAGES
+
+
+def test_untraced_ops_record_nothing_and_match_traced_bytes(
+        ec_cluster_run):
+    """With blkin_trace_all off no tracer records a span, and the
+    shards a write stores and the bytes a degraded read returns are
+    those of the traced run."""
+    run = ec_cluster_run
+    assert run["untraced_write_spans"] == []
+    assert run["untraced_read_new_spans"] == 0
+    plain, traced = run["shards"]
+    assert len(plain) == 4 and plain == traced
+    assert run["untraced_read"] == run["traced_read"] == run["payload"]
+
+
+def test_client_op_wait_feeds_the_dequeue_histogram():
+    """Every client op, traced or not, observes its wait from the
+    messenger's queue to dispatch in op_before_dequeue_op_lat."""
+    c = MiniCluster(n_osd=3, threaded=True)
+    try:
+        c.wait_all_up()
+        r = c.rados()
+        r.pool_create("rp", pg_num=4)
+        io = r.open_ioctx("rp")
+        for i in range(3):
+            io.write_full(f"o{i}", b"x" * 100)
+        counts = [d.perf.dump()["op_before_dequeue_op_lat"]["count"]
+                  for d in c.osds.values()]
+        assert sum(counts) >= 3
+    finally:
+        c.shutdown()
+
+
+def test_recv_stamp_stays_off_the_wire():
+    """A stamped message crosses a TCP connection without its stamp:
+    the stamp is the receiver's, never encoded."""
+    import time
+    from ceph_tpu.msg.messages import OSDOp
+    from ceph_tpu.msg.messenger import Dispatcher, Messenger
+    from ceph_tpu.msg.tcp import TcpNet, pick_free_ports
+
+    class Collector(Dispatcher):
+        def __init__(self):
+            self.got = []
+
+        def ms_dispatch(self, msg):
+            self.got.append(msg)
+            return True
+
+    ports = pick_free_ports(2)
+    net = TcpNet({"a": ("127.0.0.1", ports[0]),
+                  "b": ("127.0.0.1", ports[1])})
+    ma, mb = Messenger.create(net, "a"), Messenger.create(net, "b")
+    cb = Collector()
+    mb.add_dispatcher(cb)
+    ma.start()
+    mb.start()
+    try:
+        msg = OSDOp(oid="o", op="write", tid=3, trace=new_trace())
+        msg.recv_stamp = 12.5
+        assert ma.connect("b").send_message(msg)
+        end = time.monotonic() + 10
+        while not cb.got and time.monotonic() < end:
+            time.sleep(0.01)
+        assert cb.got and cb.got[0].tid == 3
+        assert cb.got[0].recv_stamp is None
+        assert cb.got[0].trace == msg.trace
+    finally:
+        ma.shutdown()
+        mb.shutdown()
+
+
+def test_span_dump_and_tree_carry_the_timeline():
+    """Dumped spans carry start and end; the rendered tree gives each
+    span's offset from its root."""
+    from ceph_tpu.common.tracing import format_tree, sibling_of
+    t = Tracer("osd.0")
+    root = new_trace()
+    t.record_span(root, "objecter_op:write", 10.0, 10.5)
+    t.record_span(sibling_of(child_of(root)), "ms_queue:OSDOp",
+                  10.1, 10.25)
+    d = {s["name"]: s for s in t.dump()}
+    assert d["ms_queue:OSDOp"]["start"] == 10.1
+    assert d["ms_queue:OSDOp"]["end"] == 10.25
+    assert d["ms_queue:OSDOp"]["parent"] == root["span"]
+    lines = format_tree(t.dump())
+    assert lines[0].startswith("objecter_op:write [osd.0] +0.000000s")
+    assert "ms_queue:OSDOp [osd.0] +0.100000s 0.150000s" in lines[1]

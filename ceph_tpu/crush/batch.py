@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..common.log import dout
 from ._ln_tables import RH_LH_TBL, LL_TBL
 from .hashes import _mix
 from .types import (
@@ -206,6 +207,31 @@ def _mono_key(u):
                      jnp.where(u == 65535, jnp.int64(65534), u))
 
 
+#: ln16 in key order (the _mono_key swap), for host-side table work
+_LN16_KEYED = _LN16.copy()
+_LN16_KEYED[[65534, 65535]] = _LN16[[65535, 65534]]
+#: spans probed for the tie bound; a weight whose ties could be wider
+#: than this falls back to the full 16-probe search
+_TIE_SPAN_MAX = 32
+#: _TIE_GAP[j-1] = the smallest ln16 rise over j consecutive keys (key
+#: space).  Nondecreasing in j: a longer span contains a shorter one.
+_TIE_GAP = np.array([(_LN16_KEYED[j:] - _LN16_KEYED[:-j]).min()
+                     for j in range(1, _TIE_SPAN_MAX + 1)])
+
+
+def tie_bound(w: int) -> int:
+    """Upper bound on how far below the class winner's key the first
+    key of equal draw can lie at weight w (the tie floor of _straw2).
+
+    Keys a < b draw equal only if their |ln| differ by less than w, and
+    they differ by at least _TIE_GAP[b-a-1]; so b - a never exceeds the
+    number of spans whose smallest rise is below w.  The bound is
+    monotone in w, so a map's largest class weight bounds all of its
+    classes."""
+    n = int(np.searchsorted(_TIE_GAP, w, side="left"))
+    return U16 if n >= _TIE_SPAN_MAX else n
+
+
 def _div_trunc(a, b):
     """C truncating signed division, b > 0."""
     q = jnp.abs(a) // jnp.maximum(b, 1)
@@ -237,6 +263,7 @@ class _StaticCfg:
     max_depth: int
     n_class_max: int
     use_classes: bool
+    tie_probes: int
     first_valid: int
 
 
@@ -277,6 +304,10 @@ class _CmView:
         return self.static.use_classes
 
     @property
+    def tie_probes(self):
+        return self.static.tie_probes
+
+    @property
     def max_devices(self):
         return self.static.max_devices
 
@@ -311,6 +342,10 @@ class CompiledCrushMap:
     class_w: jnp.ndarray | None = None
     n_class_max: int = 0
     use_classes: bool = False
+    #: binary-search steps of the class path's tie floor: the bit length
+    #: of tie_bound at the map's largest class weight (at most 16; 0
+    #: on the direct path)
+    tie_probes: int = 0
     #: id of any non-empty bucket (safe target for masked lanes)
     first_valid: int = -1
 
@@ -366,6 +401,7 @@ class CompiledCrushMap:
             n_positions=self.n_positions, max_depth=self.max_depth,
             n_class_max=self.n_class_max,
             use_classes=self.use_classes,
+            tie_probes=self.tie_probes,
             first_valid=self.first_valid)
         with jax.enable_x64(True):
             fn = _RULE_JIT.get(static)
@@ -479,6 +515,7 @@ def compile_map(map_: CrushMap, choose_args=None,
     # evaluates ln only on the C class winners instead of all I items
     class_lists: dict[tuple[int, int], list[int]] = {}
     cmax = 1
+    wmax = 0
     for bi, b in enumerate(map_.buckets):
         if b is None:
             continue
@@ -490,8 +527,14 @@ def compile_map(map_: CrushMap, choose_args=None,
                     if w > 0}
             class_lists[(p, bi)] = list(seen)
             cmax = max(cmax, len(seen))
+            wmax = max([wmax, *seen])
     use_classes = (cmax <= CLASS_PATH_MAX if class_path is None
                    else class_path) and LN16_MONO_BY_SWAP
+    # 0 on the direct path, so its executable never keys on weights
+    tie_probes = tie_bound(wmax).bit_length() if use_classes else 0
+    dout("crush", 10).write(
+        "compile_map: use_classes %s n_class_max %d tie_probes %d",
+        use_classes, cmax, tie_probes)
     class_of = np.full((P, B, I), -1, dtype=np.int32)
     class_w = np.ones((P, B, cmax), dtype=np.int64)
     for (p, bi), seen in class_lists.items():
@@ -510,7 +553,7 @@ def compile_map(map_: CrushMap, choose_args=None,
             max_devices=map_.max_devices, max_buckets=B, n_positions=P,
             max_depth=max_depth, class_of=jnp.asarray(class_of),
             class_w=jnp.asarray(class_w), n_class_max=cmax,
-            use_classes=use_classes,
+            use_classes=use_classes, tie_probes=tie_probes,
             first_valid=next(
                 (-1 - bi for bi, b in enumerate(map_.buckets)
                  if b is not None and b.size > 0), -1))
@@ -565,11 +608,13 @@ def _straw2(cm: CompiledCrushMap, bidx, x, r, position):
         # key range onto the winning draw — the C core's strict->
         # update means the FIRST index in that range wins, not the
         # max-key one.  kk = min{key : ln16(unkey) >= thr}, found by
-        # 16-step binary search in key space (C lanes, not I)
+        # binary search in key space (C lanes, not I).  The floor lies
+        # at most tie_bound(w) <= 2^tie_probes - 1 keys below kmax, so
+        # tie_probes halvings of that window reach it
         x_thr = LN_BIAS - (k + 1) * cw + 1
-        lo = jnp.zeros_like(kmax)
+        lo = jnp.maximum(kmax - ((1 << cm.tie_probes) - 1), 0)
         hi = jnp.maximum(kmax, 0)
-        for _ in range(16):
+        for _ in range(cm.tie_probes):
             mid = (lo + hi) >> 1
             ok = crush_ln16(_mono_key(mid)) >= x_thr
             hi = jnp.where(ok, mid, hi)

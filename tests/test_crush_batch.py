@@ -292,3 +292,118 @@ def test_class_path_ln_boundary_and_wide_sweep():
     for x in (0, 31337, 65534, 65535, 119_999):
         want = mapper.do_rule(m, 0, x, 3, list(weight))
         assert list(r_on[x][:np.asarray(n_on)[x]]) == want, f"x={x}"
+
+
+# -- tie-floor bound of the class path (tier-1 guard of the search) --------
+
+def _brute_tie_floor(w):
+    """For every key, the first key of equal draw at weight w, taken
+    key by key from the ln table in the class path's key order."""
+    from ceph_tpu.crush import batch as B
+    keys = np.arange(65536)
+    q = (B.LN_BIAS - B._LN16_KEYED) // w
+    starts = np.r_[True, q[1:] != q[:-1]]
+    return np.maximum.accumulate(np.where(starts, keys, 0))
+
+
+def _bounded_search(w, probes):
+    """_straw2's tie-floor search, in numpy, for every key as kmax."""
+    from ceph_tpu.crush import batch as B
+    kmax = np.arange(65536, dtype=np.int64)
+    absln = B.LN_BIAS - B._LN16_KEYED
+    x_thr = B.LN_BIAS - (absln // w + 1) * w + 1
+    lo = np.maximum(kmax - ((1 << probes) - 1), 0)
+    hi = kmax.copy()
+    for _ in range(probes):
+        mid = (lo + hi) >> 1
+        ok = B._LN16_KEYED[mid] >= x_thr
+        hi = np.where(ok, mid, hi)
+        lo = np.where(ok, lo, mid + 1)
+    return hi
+
+
+@pytest.mark.parametrize("w,span,probes", [
+    (0x10000, 1, 1),       # osdmaptool --createsimple OSDs
+    (0x140000, 1, 1),      # its 20-OSD hosts
+    (0x7d00000, 1, 2),
+    (0xFFFF0000, 11, 4),
+    (0xFFFFFFFF, 11, 4),   # the largest u32 weight
+])
+def test_tie_bound_covers_brute_force_floor(w, span, probes):
+    """The bound compile_map takes its probe count from is never below
+    the widest tie the ln table makes at w, and the bounded search
+    finds the brute-force floor for every key."""
+    from ceph_tpu.crush import batch as B
+    floor = _brute_tie_floor(w)
+    widest = int((np.arange(65536) - floor).max())
+    assert widest == span
+    assert widest <= B.tie_bound(w)
+    assert B.tie_bound(w).bit_length() == probes
+    assert np.array_equal(_bounded_search(w, probes), floor)
+    # the bound is monotone in w, so no u32 weight needs more probes
+    assert (np.diff(B._TIE_GAP) >= 0).all()
+    assert B.tie_bound(0xFFFFFFFF).bit_length() == 4
+    assert B.tie_bound(1 << 62).bit_length() == 16
+
+
+def _tie_decided_xs(w, n_items, want):
+    """xs whose first draws (r = 0..2) in a flat bucket of n_items
+    equal weights w are won by an item other than the max-key one:
+    the draws the tie floor decides."""
+    from ceph_tpu.crush import batch as B
+    from ceph_tpu.crush.hashes import hash32_3
+    xs = np.arange(60_000, dtype=np.int64)
+    hit = np.zeros(len(xs), dtype=bool)
+    ids = np.tile(np.arange(n_items), len(xs))
+    for r in range(3):
+        u = hash32_3(np.repeat(xs, n_items), ids,
+                     np.full(len(ids), r)).astype(np.int64)
+        u = (u & 0xFFFF).reshape(len(xs), n_items)
+        draws = -((B.LN_BIAS - B._LN16[u]) // w)
+        key = np.where(u == 65534, 65535, np.where(u == 65535, 65534, u))
+        hit |= draws.argmax(1) != key.argmax(1)
+    return xs[hit][:want]
+
+
+def _parity(m, weight, xs, result_max, probes):
+    c_on = compile_map(m)
+    c_off = compile_map(m, class_path=False)
+    assert c_on.use_classes and c_on.tie_probes == probes
+    assert not c_off.use_classes and c_off.tie_probes == 0
+    r_on, n_on = c_on.map_batch(xs, weight, 0, result_max,
+                                return_counts=True)
+    r_off, n_off = c_off.map_batch(xs, weight, 0, result_max,
+                                   return_counts=True)
+    r_on, n_on = np.asarray(r_on), np.asarray(n_on)
+    assert (r_on == np.asarray(r_off)).all()
+    assert (n_on == np.asarray(n_off)).all()
+    for i in range(0, len(xs), 17):
+        want = mapper.do_rule(m, 0, int(xs[i]), result_max, list(weight))
+        assert list(r_on[i][:n_on[i]]) == want, f"x={xs[i]}"
+
+
+@pytest.mark.parametrize("shape", ["simple", "tie_heavy"])
+def test_bounded_tie_floor_matches_direct_path(shape):
+    """The class path with its weight-bounded tie-floor search maps
+    exactly as the direct path and the scalar engine do, on an
+    osdmaptool-style map with an OSD out and one at crush weight 0,
+    and on the tie-heavy flat map."""
+    if shape == "simple":
+        from ceph_tpu.osd.osdmap import OSDMap
+        om = OSDMap()
+        om.build_simple(200, osds_per_host=20)
+        om.osd_weight[7] = 0
+        host = om.crush.buckets[4]
+        host.item_weights[2] = 0         # osd 82
+        m = om.crush
+        weight = np.asarray(om.osd_weight, dtype=np.int64)
+        xs = np.random.default_rng(24).integers(
+            0, 1 << 32, size=400, dtype=np.int64)
+        _parity(m, weight, xs, 3, probes=1)
+    else:
+        m = build_flat([0xFFFF0000] * 20)
+        weight = np.full(20, 0x10000, dtype=np.int64)
+        xs = _tie_decided_xs(0xFFFF0000, 20, 100)
+        assert len(xs) == 100
+        xs = np.concatenate([xs, np.arange(300, dtype=np.int64)])
+        _parity(m, weight, xs, 3, probes=4)

@@ -264,6 +264,8 @@ class _StaticCfg:
     n_class_max: int
     use_classes: bool
     tie_probes: int
+    #: ((width, leaf_width), ...) per choose step, see _rule_draws
+    draw_widths: tuple
     first_valid: int
 
 
@@ -316,6 +318,18 @@ class _CmView:
 _RULE_JIT: dict = {}
 
 
+def _rule_fn(static: _StaticCfg):
+    """map_batch's jitted rule for one static config: (arrays, xs,
+    weight) -> (placements, counts), xs batched."""
+    fn = _RULE_JIT.get(static)
+    if fn is None:
+        def one(arrays, x, weight):
+            return _do_rule_one(_CmView(*arrays, static), static, x, weight)
+        fn = jax.jit(jax.vmap(one, in_axes=(None, 0, None)))
+        _RULE_JIT[static] = fn
+    return fn
+
+
 #: class-path cutoff: with more distinct weights per bucket than this,
 #: the masked per-class max (I x C compares per draw) costs more than
 #: the ln gathers it saves and the engine keeps the direct path
@@ -346,6 +360,11 @@ class CompiledCrushMap:
     #: of tie_bound at the map's largest class weight (at most 16; 0
     #: on the direct path)
     tie_probes: int = 0
+    #: per rule (None where the map has no rule), one (width,
+    #: leaf_width) pair per choose step: how many lanes of a bucket row
+    #: its straw2 draws read (leaf_width: the chooseleaf inner descent's,
+    #: else 0).  See _rule_draws
+    draw_widths: tuple = ()
     #: id of any non-empty bucket (safe target for masked lanes)
     first_valid: int = -1
 
@@ -356,17 +375,41 @@ class CompiledCrushMap:
         16.16 reweight vector (device in/out/partial).  Returns
         (N, result_max) int32 placements (CRUSH_ITEM_NONE holes),
         optionally with per-row result counts."""
+        fn = _rule_fn(self.static_cfg(ruleno, result_max))
+        with jax.enable_x64(True):
+            xs = jnp.asarray(xs, dtype=jnp.int64)
+            weight = jnp.asarray(weight, dtype=jnp.int64)
+            # the placement tables were staged once at compile_map;
+            # under CEPH_TPU_JAXGUARD an implicit transfer inside the
+            # batched mapping dispatch is an error
+            from ..common import jaxguard
+            with jaxguard.guard_transfers():
+                res, cnt = fn(self.arrays(), xs, weight)
+        if return_counts:
+            return res, cnt
+        return res
+
+    def arrays(self) -> tuple:
+        """The tables map_batch's executable takes, in _CmView order."""
+        return (self.items, self.ids, self.weights, self.sizes,
+                self.btypes, self.valid, self.class_of, self.class_w)
+
+    def static_cfg(self, ruleno=0, result_max=None) -> _StaticCfg:
+        """The trace-time key of map_batch's executable for this rule
+        (result_max as map_batch takes it)."""
         if not (0 <= ruleno < len(self.map_.rules)) or \
                 self.map_.rules[ruleno] is None:
             raise BatchUnsupported(f"no rule {ruleno}")
+        if ruleno >= len(self.draw_widths) or \
+                self.draw_widths[ruleno] is None:
+            # its draw widths come from compile_map's walk of the rules
+            raise BatchUnsupported(
+                f"rule {ruleno} was added after compile_map")
         rule = self.map_.rules[ruleno]
-        choose_ops = (CRUSH_RULE_CHOOSE_FIRSTN, CRUSH_RULE_CHOOSE_INDEP,
-                      CRUSH_RULE_CHOOSELEAF_FIRSTN,
-                      CRUSH_RULE_CHOOSELEAF_INDEP)
         if result_max is None:
             # a choose step with arg1 <= 0 means numrep = result_max
             # (mapper.c:972-976): no sensible default exists
-            if any(s.op in choose_ops and s.arg1 <= 0 for s in rule.steps):
+            if any(s.op in _CHOOSE_OPS and s.arg1 <= 0 for s in rule.steps):
                 raise BatchUnsupported(
                     f"rule {ruleno} has a choose step with numrep <= 0 "
                     "(numrep = result_max - pass result_max explicitly, "
@@ -378,7 +421,7 @@ class CompiledCrushMap:
             for s in rule.steps:
                 if s.op == CRUSH_RULE_TAKE:
                     wmax = 1
-                elif s.op in choose_ops:
+                elif s.op in _CHOOSE_OPS:
                     wmax *= s.arg1
                 elif s.op == CRUSH_RULE_EMIT:
                     total += wmax
@@ -391,7 +434,7 @@ class CompiledCrushMap:
                   or (st.arg1 < 0 and m.bucket(st.arg1) is not None))
              if st.op == CRUSH_RULE_TAKE else False)
             for st in rule.steps)
-        static = _StaticCfg(
+        return _StaticCfg(
             steps=steps, result_max=int(result_max),
             tries=m.choose_total_tries + 1,
             local_retries=m.choose_local_tries,
@@ -402,29 +445,8 @@ class CompiledCrushMap:
             n_class_max=self.n_class_max,
             use_classes=self.use_classes,
             tie_probes=self.tie_probes,
+            draw_widths=self.draw_widths[ruleno],
             first_valid=self.first_valid)
-        with jax.enable_x64(True):
-            fn = _RULE_JIT.get(static)
-            if fn is None:
-                def one(arrays, x, weight, static=static):
-                    cm = _CmView(*arrays, static)
-                    return _do_rule_one(cm, static, x, weight)
-                fn = jax.jit(jax.vmap(one, in_axes=(None, 0, None)))
-                _RULE_JIT[static] = fn
-            arrays = (self.items, self.ids, self.weights, self.sizes,
-                      self.btypes, self.valid, self.class_of,
-                      self.class_w)
-            xs = jnp.asarray(xs, dtype=jnp.int64)
-            weight = jnp.asarray(weight, dtype=jnp.int64)
-            # the placement tables were staged once at compile_map;
-            # under CEPH_TPU_JAXGUARD an implicit transfer inside the
-            # batched mapping dispatch is an error
-            from ..common import jaxguard
-            with jaxguard.guard_transfers():
-                res, cnt = fn(arrays, xs, weight)
-        if return_counts:
-            return res, cnt
-        return res
 
 
 def compile_map(map_: CrushMap, choose_args=None,
@@ -545,6 +567,16 @@ def compile_map(map_: CrushMap, choose_args=None,
             w = int(weights[p, bi, i])
             if w > 0:
                 class_of[p, bi, i] = lut[w]
+    draw_widths = []
+    for ruleno, rule in enumerate(map_.rules):
+        if rule is None:
+            draw_widths.append(None)
+            continue
+        widths, fill = _rule_draws(map_, rule, items, sizes, btypes)
+        draw_widths.append(widths)
+        dout("crush", 10).write(
+            "compile_map: rule %d draw widths %s lane fill %.3f "
+            "(%.3f at the map-wide width %d)", ruleno, widths, *fill, I)
     with jax.enable_x64(True):  # weights table must stay int64
         return CompiledCrushMap(
             map_=map_, items=jnp.asarray(items), ids=jnp.asarray(ids),
@@ -554,16 +586,103 @@ def compile_map(map_: CrushMap, choose_args=None,
             max_depth=max_depth, class_of=jnp.asarray(class_of),
             class_w=jnp.asarray(class_w), n_class_max=cmax,
             use_classes=use_classes, tie_probes=tie_probes,
+            draw_widths=tuple(draw_widths),
             first_valid=next(
                 (-1 - bi for bi, b in enumerate(map_.buckets)
                  if b is not None and b.size > 0), -1))
 
 
+_CHOOSE_OPS = (CRUSH_RULE_CHOOSE_FIRSTN, CRUSH_RULE_CHOOSE_INDEP,
+               CRUSH_RULE_CHOOSELEAF_FIRSTN, CRUSH_RULE_CHOOSELEAF_INDEP)
+_LEAF_OPS = (CRUSH_RULE_CHOOSELEAF_FIRSTN, CRUSH_RULE_CHOOSELEAF_INDEP)
+
+
+def _reach(start, target, items, sizes, btypes):
+    """What a _descend toward type `target` from any bucket of `start`
+    (bool mask over bucket indices) can do: (draw, land), the buckets
+    it can call _straw2 on and the buckets of type `target` it can stop
+    at.  It draws from the start bucket whatever its type, then from
+    every child bucket not of the target type (a bucket's children are
+    all loadable: compile_map checked)."""
+    draw = np.zeros(len(btypes), dtype=bool)
+    land = np.zeros(len(btypes), dtype=bool)
+    frontier = start
+    while frontier.any():
+        draw |= frontier
+        ch = items[frontier, :sizes[frontier].max()]
+        ch = -1 - ch[ch < 0]        # child buckets (pad lanes hold 0)
+        stops = btypes[ch] == target
+        land[ch[stops]] = True
+        frontier = np.zeros_like(draw)
+        frontier[ch[~stops]] = True
+        frontier &= ~draw
+    return draw, land
+
+
+def _rule_draws(map_, rule, items, sizes, btypes):
+    """Static straw2 widths of a rule: per choose step, (width,
+    leaf_width), the largest bucket its descent can draw from and the
+    largest its chooseleaf inner descent can (0 for a plain choose).
+    Lanes past a bucket's size are dead, so a draw reads only that
+    many lanes of a row.  Walks the steps as _do_rule_one does, with
+    the buckets the working vector can hold: a take's bucket, a choose
+    step's landing buckets; where it can hold none, a draw comes only
+    from the masked safe bucket, which keeps the map-wide width I.
+
+    Also returns the lane fill (mean size of the buckets a descent can
+    draw from over its width, summed over the rule's descents) at these
+    widths and at I."""
+    I = items.shape[1]
+    start = np.zeros(len(sizes), dtype=bool)
+    widths, fills = [], []
+
+    def width(draw):
+        if not draw.any():
+            return I
+        w = max(int(sizes[draw].max()), 1)
+        fills.append((float(sizes[draw].mean()), w))
+        return w
+
+    for st in rule.steps:
+        if st.op == CRUSH_RULE_TAKE:
+            if 0 <= st.arg1 < map_.max_devices:
+                start = np.zeros_like(start)
+            elif st.arg1 < 0 and map_.bucket(st.arg1) is not None:
+                start = np.zeros_like(start)
+                start[-1 - st.arg1] = True
+        elif st.op in _CHOOSE_OPS:
+            draw, start = _reach(start, st.arg2, items, sizes, btypes)
+            w = width(draw)
+            lw = 0
+            if st.op in _LEAF_OPS:
+                draw, start = _reach(start, 0, items, sizes, btypes)
+                lw = width(draw)
+            widths.append((w, lw))
+        elif st.op == CRUSH_RULE_EMIT:
+            start = np.zeros_like(start)
+    useful = sum(m for m, _ in fills)
+    fill = ((useful / sum(w for _, w in fills), useful / (I * len(fills)))
+            if fills else (1.0, 1.0))
+    return tuple(widths), fill
+
+
 # ---------------------------------------------------------------------------
 # core choose primitives (single-x; vmapped by map_batch)
 
-def _straw2(cm: CompiledCrushMap, bidx, x, r, position):
-    """bucket_straw2_choose (mapper.c:361-390) for dense bucket bidx.
+def _lanes(table, index, width):
+    """The first `width` lanes of table[index] (index: leading indices
+    down to the row): a draw reads no lane past the widest bucket it
+    can reach.  Slice the table, then gather whole rows: under vmap a
+    partial-row read (lax.dynamic_slice) becomes a gather that the TPU
+    compiler expands into a loop over the batch (a 10k-OSD remap pass
+    took 8x as long on a v5e)."""
+    return table[..., :width][index]
+
+
+def _straw2(cm: CompiledCrushMap, bidx, x, r, position, width):
+    """bucket_straw2_choose (mapper.c:361-390) for dense bucket bidx,
+    over its first `width` lanes (static; no bucket the call site can
+    reach is wider, see _rule_draws).
 
     Two bit-identical formulations:
 
@@ -585,13 +704,12 @@ def _straw2(cm: CompiledCrushMap, bidx, x, r, position):
       than CLASS_PATH_MAX distinct weights in a bucket, where the
       (I x C) class masking would outgrow the gather it saves.
     """
-    ids = cm.ids[bidx]
     pos = jnp.minimum(position, cm.n_positions - 1)
+    ids = _lanes(cm.ids, (bidx,), width)
     u = jhash3(x, ids, r).astype(jnp.int64) & U16
-    I = cm.items.shape[1]
-    lane_ok = jnp.arange(I) < cm.sizes[bidx]
+    lane_ok = jnp.arange(width) < cm.sizes[bidx]
     if cm.use_classes:
-        cls = cm.class_of[pos, bidx]                   # (I,) -1 = dead
+        cls = _lanes(cm.class_of, (pos, bidx), width)  # -1 = dead
         cw = cm.class_w[pos, bidx]                     # (C,)
         ue = jnp.where(lane_ok & (cls >= 0), u, jnp.int64(-1))
         uk = _mono_key(ue)          # ln16 is monotone in key space
@@ -621,12 +739,12 @@ def _straw2(cm: CompiledCrushMap, bidx, x, r, position):
             lo = jnp.where(ok, lo, mid + 1)
         # first item index whose draw equals the class draw
         idx_c = jnp.where(cmask & (uk[None, :] >= hi[:, None]),
-                          jnp.arange(I)[None, :], I).min(axis=1)
+                          jnp.arange(width)[None, :], width).min(axis=1)
         best = draws.max()
-        idx = jnp.where(draws == best, idx_c, I).min()
+        idx = jnp.where(draws == best, idx_c, width).min()
         idx = jnp.where(best == S64_MIN, 0, idx)       # all-dead bucket
         return cm.items[bidx, idx]
-    w = cm.weights[pos, bidx]
+    w = _lanes(cm.weights, (pos, bidx), width)
     ln = crush_ln16(u) - LN_BIAS
     draws = jnp.where(w > 0, _div_trunc(ln, w), S64_MIN)
     draws = jnp.where(lane_ok, draws, S64_MIN - 1)
@@ -655,9 +773,11 @@ def _is_out(cm: CompiledCrushMap, weight, item, x):
         (w == 0) | ((jhash2(x, item).astype(jnp.int64) & U16) >= w)))
 
 
-def _descend(cm: CompiledCrushMap, x, r, start_item, target_type, position):
+def _descend(cm: CompiledCrushMap, x, r, start_item, target_type, position,
+             width):
     """Straw2-walk from bucket `start_item` down until an item of
-    target_type or a dead end.  Returns (item, parent, code):
+    target_type or a dead end, each draw `width` lanes wide.  Returns
+    (item, parent, code):
     parent = bucket the item was chosen from (for in-bucket retries);
     code = _HIT | _EMPTY (a size-0 bucket was reached) | _BAD (invalid
     item id / non-bucket of wrong type, mapper.c:540,553).
@@ -674,7 +794,7 @@ def _descend(cm: CompiledCrushMap, x, r, start_item, target_type, position):
         cur, item, code, done, depth = st
         bidx = -1 - cur
         empty = cm.sizes[bidx] == 0
-        nxt = _straw2(cm, bidx, x, r, position)
+        nxt = _straw2(cm, bidx, x, r, position, width)
         ntype = _item_type(cm, nxt)
         bad = (nxt >= cm.max_devices) | \
               ((ntype != target_type) & ~_bucket_ok(cm, nxt))
@@ -698,9 +818,12 @@ def _descend(cm: CompiledCrushMap, x, r, start_item, target_type, position):
 
 def _firstn_rep(cm, x, take_item, weight, rep, parent_r, target_type,
                 out_arr, outpos, tries, local_retries, vary_r, stable,
-                recurse_tries, recurse_to_leaf, out2_arr, result_max):
+                recurse_tries, recurse_to_leaf, out2_arr, result_max,
+                widths):
     """One replica of crush_choose_firstn (mapper.c:460-645): descend,
-    reject/collide retry cascade.  Returns (item, leaf, skipped)."""
+    reject/collide retry cascade.  widths: the step's (width,
+    leaf_width).  Returns (item, leaf, skipped)."""
+    width, leaf_width = widths
     pos_idx = jnp.arange(result_max)
 
     def cond(st):
@@ -711,7 +834,7 @@ def _firstn_rep(cm, x, take_item, weight, rep, parent_r, target_type,
         in_item, ftotal, flocal, item, leaf, done, skipped = st
         r = rep + parent_r + ftotal
         item_n, parent, code = _descend(cm, x, r, in_item, target_type,
-                                        outpos)
+                                        outpos, width)
         bad = code == _BAD          # → skip this replica (no retry)
         empty = code == _EMPTY      # → reject (retry path)
         ok = code == _HIT
@@ -721,7 +844,7 @@ def _firstn_rep(cm, x, take_item, weight, rep, parent_r, target_type,
             rep_eff = jnp.int32(0) if stable else outpos
             leaf_n, leaf_ok = _leaf_firstn(
                 cm, x, item_n, weight, rep_eff, sub_r, recurse_tries,
-                local_retries, out2_arr, outpos, result_max)
+                local_retries, out2_arr, outpos, result_max, leaf_width)
             leaf_ok = leaf_ok | (item_n >= 0)
             leaf_n = jnp.where(item_n >= 0, item_n, leaf_n)
         else:
@@ -752,7 +875,7 @@ def _firstn_rep(cm, x, take_item, weight, rep, parent_r, target_type,
 
 
 def _leaf_firstn(cm, x, bucket_item, weight, rep_eff, parent_r, tries,
-                 local_retries, out2_arr, outpos, result_max):
+                 local_retries, out2_arr, outpos, result_max, width):
     """Inner chooseleaf descent (mapper.c:566-595 → one-replica recursive
     crush_choose_firstn with type 0, no further recursion).
     Returns (leaf, success)."""
@@ -765,7 +888,8 @@ def _leaf_firstn(cm, x, bucket_item, weight, rep_eff, parent_r, tries,
     def body(st):
         in_item, ftotal, flocal, item, done, succ = st
         r = rep_eff + parent_r + ftotal
-        item_n, parent, code = _descend(cm, x, r, in_item, 0, outpos)
+        item_n, parent, code = _descend(cm, x, r, in_item, 0, outpos,
+                                        width)
         bad = code == _BAD
         empty = code == _EMPTY
         ok = code == _HIT
@@ -791,7 +915,7 @@ def _leaf_firstn(cm, x, bucket_item, weight, rep_eff, parent_r, tries,
 
 def _choose_firstn(cm, x, take_item, weight, numrep, target_type,
                    count0, tries, recurse_tries, local_retries,
-                   recurse_to_leaf, vary_r, stable, result_max):
+                   recurse_to_leaf, vary_r, stable, result_max, widths):
     """crush_choose_firstn over all replicas of one take segment.  The
     C core hands each take item a fresh output segment (o+osize, j=0,
     mapper.c:1038-1043), so the segment always starts at position 0 and
@@ -807,7 +931,8 @@ def _choose_firstn(cm, x, take_item, weight, numrep, target_type,
         item, leaf, skipped = _firstn_rep(
             cm, x, take_item, weight, jnp.int32(rep_off), jnp.int32(0),
             target_type, out, outpos, tries, local_retries, vary_r,
-            stable, recurse_tries, recurse_to_leaf, out2, result_max)
+            stable, recurse_tries, recurse_to_leaf, out2, result_max,
+            widths)
         write = active & ~skipped
         out = jnp.where(write & (pos_idx == outpos), item, out)
         if recurse_to_leaf:
@@ -818,7 +943,7 @@ def _choose_firstn(cm, x, take_item, weight, numrep, target_type,
 
 
 def _leaf_indep(cm, x, bucket_item, weight, numrep, parent_r, tries,
-                rep):
+                rep, width):
     """Inner chooseleaf descent for indep (mapper.c:781-790 → one-slot
     recursive crush_choose_indep, type 0).  Returns leaf or NONE."""
     def cond(st):
@@ -828,7 +953,8 @@ def _leaf_indep(cm, x, bucket_item, weight, numrep, parent_r, tries,
     def body(st):
         ft, leaf, done = st
         r = rep + parent_r + numrep * ft
-        item, parent, code = _descend(cm, x, r, bucket_item, 0, rep)
+        item, parent, code = _descend(cm, x, r, bucket_item, 0, rep,
+                                      width)
         ok = code == _HIT
         hard = code == _BAD
         reject = ok & _is_out(cm, weight, item, x)
@@ -845,11 +971,13 @@ def _leaf_indep(cm, x, bucket_item, weight, numrep, parent_r, tries,
 
 
 def _choose_indep(cm, x, take_item, weight, left0, numrep, target_type,
-                  tries, recurse_tries, recurse_to_leaf, result_max):
+                  tries, recurse_tries, recurse_to_leaf, result_max,
+                  widths):
     """crush_choose_indep (mapper.c:655-830) over one take segment
     (segment-relative positions, see _choose_firstn): breadth-first,
     positionally stable; holes become CRUSH_ITEM_NONE.
     Returns (seg_out, seg_out2) with slots [0, left0) filled."""
+    width, leaf_width = widths
     pos_idx = jnp.arange(result_max)
     in_range = pos_idx < left0
     out = jnp.where(in_range, CRUSH_ITEM_UNDEF, 0).astype(jnp.int32)
@@ -867,7 +995,7 @@ def _choose_indep(cm, x, take_item, weight, left0, numrep, target_type,
             todo = (rep < endpos) & (slot_val == CRUSH_ITEM_UNDEF)
             rr = rep + numrep * ftotal
             item, parent, code = _descend(cm, x, rr, take_item,
-                                          target_type, outpos)
+                                          target_type, outpos, width)
             ok = code == _HIT
             hard = code == _BAD  # → NONE immediately (mapper.c:731,758)
             collide = ok & jnp.any(in_range & (out == item))
@@ -875,7 +1003,7 @@ def _choose_indep(cm, x, take_item, weight, left0, numrep, target_type,
                 leaf = jnp.where(
                     item < 0,
                     _leaf_indep(cm, x, item, weight, numrep, rr,
-                                recurse_tries, rep),
+                                recurse_tries, rep, leaf_width),
                     item)
                 leaf_fail = (item < 0) & (leaf == CRUSH_ITEM_NONE)
             else:
@@ -943,6 +1071,7 @@ def _do_rule_one(cm, static: _StaticCfg, x, weight):
     w_max = 0  # static upper bound on w_count
     pos_idx = jnp.arange(result_max)
     safe_bucket = jnp.int32(static.first_valid)
+    draw_widths = iter(static.draw_widths)
 
     for op, arg1, arg2, take_ok in static.steps:
         if op == CRUSH_RULE_TAKE:
@@ -968,13 +1097,11 @@ def _do_rule_one(cm, static: _StaticCfg, x, weight):
         elif op == CRUSH_RULE_SET_CHOOSELEAF_STABLE:
             if arg1 >= 0:
                 stable = arg1
-        elif op in (CRUSH_RULE_CHOOSE_FIRSTN, CRUSH_RULE_CHOOSE_INDEP,
-                    CRUSH_RULE_CHOOSELEAF_FIRSTN,
-                    CRUSH_RULE_CHOOSELEAF_INDEP):
+        elif op in _CHOOSE_OPS:
             firstn = op in (CRUSH_RULE_CHOOSE_FIRSTN,
                             CRUSH_RULE_CHOOSELEAF_FIRSTN)
-            recurse = op in (CRUSH_RULE_CHOOSELEAF_FIRSTN,
-                             CRUSH_RULE_CHOOSELEAF_INDEP)
+            recurse = op in _LEAF_OPS
+            widths = next(draw_widths)
             numrep = arg1
             if numrep <= 0:
                 numrep += result_max
@@ -1005,13 +1132,13 @@ def _do_rule_one(cm, static: _StaticCfg, x, weight):
                         cm, x, take, weight, numrep, arg2,
                         result_max - osize, tries, recurse_tries,
                         local_retries, recurse, vary_r, stable,
-                        result_max)
+                        result_max, widths)
                 else:
                     got = jnp.minimum(jnp.int32(numrep),
                                       result_max - osize)
                     seg_o, seg_c = _choose_indep(
                         cm, x, take, weight, got, numrep, arg2,
-                        tries, recurse_tries, recurse, result_max)
+                        tries, recurse_tries, recurse, result_max, widths)
                 got = jnp.where(wi_ok, got, 0)
                 seg_idx = jnp.clip(pos_idx - osize, 0, result_max - 1)
                 mask = (pos_idx >= osize) & (pos_idx < osize + got)

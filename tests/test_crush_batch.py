@@ -16,8 +16,9 @@ from ceph_tpu.crush.batch import BatchUnsupported, compile_map
 from ceph_tpu.crush.testing import (RULES, build_hierarchy,
                                     make_weight, map_from_spec)
 from ceph_tpu.crush.types import (
-    CRUSH_BUCKET_STRAW2, CRUSH_RULE_CHOOSELEAF_INDEP,
-    CRUSH_RULE_CHOOSE_FIRSTN, CRUSH_RULE_EMIT, CRUSH_RULE_TAKE, ChooseArg,
+    CRUSH_BUCKET_STRAW2, CRUSH_RULE_CHOOSELEAF_FIRSTN,
+    CRUSH_RULE_CHOOSELEAF_INDEP, CRUSH_RULE_CHOOSE_FIRSTN, CRUSH_RULE_EMIT,
+    CRUSH_RULE_TAKE, ChooseArg,
     CrushBucket, CrushMap, CrushRule, CrushRuleStep,
 )
 
@@ -407,3 +408,162 @@ def test_bounded_tie_floor_matches_direct_path(shape):
         assert len(xs) == 100
         xs = np.concatenate([xs, np.arange(300, dtype=np.int64)])
         _parity(m, weight, xs, 3, probes=4)
+
+
+# -- static draw widths (each straw2 draw reads only the lanes it can use) --
+
+def _build_uneven(rack_hosts=((3, 20), (7, 4, 5)), seed=0):
+    """root(type 3) -> racks(2) -> hosts(1) -> osds(0), hosts of the given
+    sizes, OSD crush weights from two classes."""
+    rng = np.random.default_rng(seed)
+    m = CrushMap()
+    m.set_tunables_profile("jewel")
+    osd = 0
+    racks = []
+    for sizes in rack_hosts:
+        hosts = []
+        for n in sizes:
+            items = list(range(osd, osd + n))
+            osd += n
+            ws = [int(rng.choice([0x10000, 0x18000])) for _ in items]
+            hosts.append(m.add_bucket(CrushBucket(
+                id=0, type=1, alg=CRUSH_BUCKET_STRAW2, items=items,
+                item_weights=ws, weight=sum(ws))))
+        hw = [m.bucket(h).weight for h in hosts]
+        racks.append(m.add_bucket(CrushBucket(
+            id=0, type=2, alg=CRUSH_BUCKET_STRAW2, items=hosts,
+            item_weights=hw, weight=sum(hw))))
+    rw = [m.bucket(r).weight for r in racks]
+    root = m.add_bucket(CrushBucket(
+        id=0, type=3, alg=CRUSH_BUCKET_STRAW2, items=racks,
+        item_weights=rw, weight=sum(rw)))
+    m.max_devices = osd
+    return m, root, racks
+
+
+def _simple(n_osd):
+    from ceph_tpu.osd.osdmap import OSDMap
+    om = OSDMap()
+    om.build_simple(n_osd, osds_per_host=20)
+    return om.crush
+
+
+def _uneven_rule(name):
+    m, root, racks = _build_uneven()
+    steps = {
+        "chooseleaf_host": [
+            CrushRuleStep(CRUSH_RULE_TAKE, root),
+            CrushRuleStep(CRUSH_RULE_CHOOSELEAF_FIRSTN, 0, 1),
+            CrushRuleStep(CRUSH_RULE_EMIT)],
+        "take_rack": [
+            CrushRuleStep(CRUSH_RULE_TAKE, racks[1]),
+            CrushRuleStep(CRUSH_RULE_CHOOSELEAF_INDEP, 0, 1),
+            CrushRuleStep(CRUSH_RULE_EMIT)],
+        "two_step": [
+            CrushRuleStep(CRUSH_RULE_TAKE, root),
+            CrushRuleStep(CRUSH_RULE_CHOOSE_FIRSTN, 2, 2),
+            CrushRuleStep(CRUSH_RULE_CHOOSELEAF_FIRSTN, 2, 1),
+            CrushRuleStep(CRUSH_RULE_EMIT)],
+        "missing_take": [
+            CrushRuleStep(CRUSH_RULE_TAKE, -999),
+            CrushRuleStep(CRUSH_RULE_CHOOSELEAF_FIRSTN, 0, 1),
+            CrushRuleStep(CRUSH_RULE_EMIT)],
+    }[name]
+    m.rules.append(CrushRule(steps=steps))
+    return m
+
+
+@pytest.mark.parametrize("case,want", [
+    # osdmaptool --createsimple: the root is narrower than its hosts
+    ("simple_200", ((10, 20),)),
+    ("simple_10000", ((500, 20),)),
+    # root -> rack -> host: draws to a host pass root (2) and racks (3)
+    ("chooseleaf_host", ((3, 20),)),
+    # a take below the root reaches only that rack's hosts (7, 4, 5)
+    ("take_rack", ((3, 7),)),
+    # choose racks from the root, then hosts from the racks
+    ("two_step", ((2, 0), (3, 20))),
+    # no bucket to start from: only masked lanes draw, map-wide width
+    ("missing_take", ((20, 20),)),
+])
+def test_draw_widths(case, want):
+    """The per-step straw2 widths compile_map derives from the map's
+    bucket tree: the widest bucket each descent can draw from."""
+    if case.startswith("simple_"):
+        m = _simple(int(case.split("_")[1]))
+    else:
+        m = _uneven_rule(case)
+    cc = compile_map(m)
+    assert cc.draw_widths == (want,)
+    assert cc.static_cfg(0, 6).draw_widths == want
+    assert max(w for pair in want for w in pair) <= cc.items.shape[1]
+
+
+def test_draw_widths_logged_with_lane_fill(monkeypatch):
+    import logging
+    from ceph_tpu.common import log
+    m = _simple(10000)
+    lines = []
+
+    class Grab(logging.Handler):
+        def emit(self, record):
+            lines.append(record.getMessage())
+    lg = logging.getLogger("ceph_tpu.crush")
+    h = Grab()
+    lg.addHandler(h)
+    monkeypatch.setitem(log._levels, "crush", 10)
+    try:
+        compile_map(m)
+    finally:
+        lg.removeHandler(h)
+    assert ("compile_map: rule 0 draw widths ((500, 20),) lane fill 1.000 "
+            "(0.520 at the map-wide width 500)") in lines
+
+
+@pytest.mark.parametrize("class_path", [True, False])
+def test_narrow_draws_match_scalar(class_path):
+    """Hosts of 3, 20 and 7 OSDs under two racks, one OSD out and one
+    partly reweighted: a chooseleaf firstn segment and a chooseleaf
+    indep (EC) segment in one rule, so one program, match the scalar
+    engine on both straw2 formulations."""
+    m, root, _ = _build_uneven(rack_hosts=((3, 20), (7,)), seed=3)
+    m.rules.append(CrushRule(steps=[
+        CrushRuleStep(CRUSH_RULE_TAKE, root),
+        CrushRuleStep(CRUSH_RULE_CHOOSELEAF_FIRSTN, 2, 1),
+        CrushRuleStep(CRUSH_RULE_EMIT),
+        CrushRuleStep(CRUSH_RULE_TAKE, root),
+        CrushRuleStep(CRUSH_RULE_CHOOSELEAF_INDEP, 3, 1),
+        CrushRuleStep(CRUSH_RULE_EMIT)]))
+    cc = compile_map(m, class_path=class_path)
+    assert cc.use_classes == class_path
+    assert cc.draw_widths == (((2, 20), (2, 20)),)
+    weight = np.full(m.max_devices, 0x10000, dtype=np.int64)
+    weight[4] = 0            # in the 20-OSD host
+    weight[25] = 0x8000      # in the 7-OSD host
+    xs = np.random.default_rng(26).integers(0, 1 << 32, size=300,
+                                            dtype=np.int64)
+    res, cnt = cc.map_batch(xs, weight, ruleno=0, result_max=5,
+                            return_counts=True)
+    res, cnt = np.asarray(res), np.asarray(cnt)
+    for i, x in enumerate(xs):
+        want = mapper.do_rule(m, 0, int(x), 5, list(weight))
+        assert list(res[i][:cnt[i]]) == want, f"x={x}"
+
+
+def test_static_cfg_keys_on_shape_not_weights():
+    """Maps that differ only in crush weights share an executable; a map
+    with one host wider than the rest draws its OSDs wider."""
+    a, b = _simple(200), _simple(200)
+    a.buckets[3].item_weights[5] = 0x18000
+    b.buckets[7].item_weights[2] = 0x8000
+    ka = compile_map(a).static_cfg(0, 3)
+    kb = compile_map(b).static_cfg(0, 3)
+    assert ka == kb and hash(ka) == hash(kb)
+    wide = _simple(200)
+    host = wide.buckets[4]
+    host.items += list(range(200, 206))
+    host.item_weights += [0x10000] * 6
+    wide.max_devices = 206
+    cw = compile_map(wide)
+    assert cw.draw_widths == (((10, 26),),)
+    assert cw.static_cfg(0, 3) != ka

@@ -103,3 +103,24 @@ def test_lrc_kernels_compile(one_chip, r, k, group):
     compiled = bm.gf_matmul_pallas_grouped.lower(
         bgp, data, group=group, tile_n=2048).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_crush_narrow_draws_compile_to_row_gathers(one_chip):
+    """A host draw (20 OSDs) under a 50-host root reads its 20-lane rows
+    as native gathers.  A partial-row read there compiles into a loop of
+    per-PG dynamic slices on the TPU, though the CPU runs it fine."""
+    from ceph_tpu.crush import batch
+    from ceph_tpu.osd.osdmap import OSDMap
+    om = OSDMap()
+    om.build_simple(1000, osds_per_host=20)
+    cc = batch.compile_map(om.crush)
+    static = cc.static_cfg(0, 3)
+    assert static.draw_widths == ((50, 20),)
+    with jax.enable_x64(True):
+        tables = tuple(shape(one_chip, a.shape, a.dtype)
+                       for a in cc.arrays())
+        hlo = batch._rule_fn(static).lower(
+            tables, shape(one_chip, (4096,), jnp.int64),
+            shape(one_chip, (om.max_osd,), jnp.int64)).compile().as_text()
+    assert "slice_sizes={1,20}" in hlo
+    assert "dynamic-slice" not in hlo

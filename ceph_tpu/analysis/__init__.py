@@ -12,7 +12,7 @@ version) plus the JAX-specific hazards that invalidate perf claims
 
 Run it from the repo root::
 
-    python -m ceph_tpu.analysis ceph_tpu/ tests/ scripts/ bench.py
+    python -m ceph_tpu.analysis ceph_tpu/ tests/ scripts/ chip_smoke.py
 
 Exit 0 means no unsuppressed findings.  Suppressions live in
 ``.cephck-baseline.json`` at the repo root and every entry MUST carry

@@ -167,8 +167,8 @@ class Suppression:
 
     def matches(self, f: Finding) -> bool:
         # exact repo-relative path only: a suffix match would let a
-        # root "bench.py" entry silently swallow findings from any
-        # future tests/bench.py too
+        # root "chip_smoke.py" entry silently swallow findings from
+        # any future tests/chip_smoke.py too
         if self.rule != f.rule or f.path != self.path:
             return False
         return self.symbol in ("", f.symbol)
@@ -366,7 +366,7 @@ def main(argv: list[str] | None = None) -> int:
         description="cephck: project-specific static analysis "
                     "(exit 0 = clean, 1 = findings, 2 = bad config)")
     ap.add_argument("paths", nargs="*",
-                    default=["ceph_tpu", "tests", "scripts", "bench.py",
+                    default=["ceph_tpu", "tests", "scripts",
                              "chip_smoke.py"],
                     help="files/dirs to scan (default: the whole tree)")
     ap.add_argument("--baseline", default=None,

@@ -710,8 +710,8 @@ host<->device boundary is crossed ONCE per batch.  A sync inside the
 per-stripe loop turns the pipeline back into
 dispatch-wait-dispatch-wait: every iteration pays the full device
 round-trip latency, and on a multi-chip mesh every chip idles behind
-it.  This is the exact hazard class PR 9 removed from the decode path
-(staging-free decode) — the rule keeps it from growing back.  The
+it.  The batched decode path exists to avoid exactly this hazard —
+the rule keeps it from growing back.  The
 check is cross-module: a loop that calls a helper (resolved through
 the project call graph) which syncs inside is flagged at the
 callsite.
@@ -1456,7 +1456,7 @@ def _error_scope(rel: str) -> bool:
     parts = rel.split("/")
     if "fixtures" in parts:
         return True
-    return parts[0] not in ("tests", "scripts", "bench.py")
+    return parts[0] not in ("tests", "scripts")
 
 
 def _broad_handler(node: ast.AST) -> str | None:

@@ -1,6 +1,5 @@
 """Where JAX keeps its persistent compile cache for programs that run
-on the chip (chip_smoke.py, bench.py, scripts/placement_bench.py,
-scripts/perf_probe.py).
+on the chip (chip_smoke.py, benchmark/run.py).
 
 A cache directory is part of the cache key, so it must not move from
 run to run: never a temp, pid or time-based path."""

@@ -227,9 +227,7 @@ OPTIONS: dict[str, Option] = {opt.name: opt for opt in [
     _o("upmap_max_deviation", T.UINT, 5, L.BASIC, runtime=True,
        desc="target max PG-count deviation per OSD"),
     _o("upmap_max_optimizations", T.UINT, 10, runtime=True),
-    # EC / bench
-    _o("ec_tpu_backend", T.STR, "pallas", L.ADVANCED,
-       enum=("xla", "pallas"), desc="bit-matmul kernel backend"),
+    # EC
     _o("ec_profile_default_k", T.UINT, 2, L.DEV),
     _o("ec_profile_default_m", T.UINT, 1, L.DEV),
     # object store

@@ -39,12 +39,11 @@ class DecodeTableCache:
     """Cost-weighted LRU of decode tables keyed by erasure signature
     (ref: ErasureCodeIsaTableCache.cc, decoding_tables_lru_length).
 
-    `cost` weights an entry against the capacity: a full-width
-    (nerrs x n) matrix — or the HBM-resident kernel object built from
-    one — is ~(k+m)/k x the footprint of the dense (nerrs x k) table,
-    so full-matrix signatures charge more and the bound stays a real
-    memory bound, not an entry count.  Values are opaque (ndarray or
-    compiled-kernel wrappers alike)."""
+    Each entry charges `cost` against the capacity, one by default, so
+    a plain signature LRU holds `capacity` entries; a caller whose
+    entries differ in size (repair programs, by matrix bytes) passes
+    the size so the bound stays a memory bound.  Values are opaque
+    (ndarray or compiled-kernel wrappers alike)."""
 
     def __init__(self, capacity: int = 2516):
         from ..common.lockdep import make_lock
@@ -112,24 +111,6 @@ def make_decode_matrix(encode_matrix: np.ndarray, k: int,
         else:
             rows.append(gf.gf_matmul(encode_matrix[e][None, :], inv_b)[0])
     return np.stack(rows).astype(np.uint8)
-
-
-def make_decode_matrix_full(encode_matrix: np.ndarray, k: int, n: int,
-                            decode_index: list[int],
-                            erasures: list[int]) -> np.ndarray:
-    """(nerrs x n) decode matrix over ALL n=k+m chunk slots.
-
-    Columns outside `decode_index` are zero, so the matmul consumes the
-    full chunk array in place — erased/unused slots contribute nothing
-    regardless of content and the survivor gather disappears entirely
-    (device-resident survivor selection: the selection IS the matrix).
-    The ISA-L analogue keeps gathering into dense buffers
-    (ErasureCodeIsa.cc:252-306); on the MXU the zero columns ride for
-    free in the same tiles."""
-    dmat = make_decode_matrix(encode_matrix, k, decode_index, erasures)
-    full = np.zeros((len(erasures), n), dtype=np.uint8)
-    full[:, decode_index] = dmat
-    return full
 
 
 class MatrixErasureCode(ErasureCode):

@@ -24,8 +24,8 @@ import numpy as np
 from .. import gf
 from ..interface import ErasureCodeProfile, ErasureCodeError, to_int, \
     sanity_check_k_m
-from ..matrix_code import MatrixErasureCode, make_decode_matrix, \
-    erasure_signature
+from ..matrix_code import DecodeTableCache, MatrixErasureCode, \
+    make_decode_matrix, erasure_signature
 from ..registry import ErasureCodePlugin
 
 EC_TPU_DEFAULT_ALIGNMENT = 32  # match isa (EC_ISA_ADDRESS_ALIGNMENT)
@@ -54,23 +54,15 @@ class ErasureCodeTpu(MatrixErasureCode):
     DEFAULT_K = "8"
     DEFAULT_M = "4"
 
-    #: decode-kernel LRU capacity in matrix-WIDTH units (byte columns):
-    #: a dense (nerrs x k) entry costs k, a full-width (nerrs x n)
-    #: entry costs n, so the bound tracks HBM footprint across mixed
-    #: signatures (ref: ErasureCodeIsaTableCache.cc
-    #: decoding_tables_lru_length, which bounds dense entries only)
-    DECODE_LRU_WIDTH = 2516 * 8
-
     def __init__(self) -> None:
         super().__init__()
         self.technique = "reed_sol_van"
         self.alignment = EC_TPU_DEFAULT_ALIGNMENT
         self._encode_mm = None          # GFMatmul for coding rows
-        from ..matrix_code import DecodeTableCache
-        #: signature -> GFMatmul/GFDecodeFull, cost-weighted LRU so
+        #: signature -> GFMatmul, an LRU of ISA-L's 2516 entries so
         #: HBM-resident decode kernels can't grow unbounded across
-        #: erasure patterns (full-width entries charge n, dense k)
-        self._decode_mm = DecodeTableCache(self.DECODE_LRU_WIDTH)
+        #: erasure patterns
+        self._decode_mm = DecodeTableCache()
 
     def init(self, profile: ErasureCodeProfile) -> None:
         profile.setdefault("plugin", "tpu")
@@ -142,77 +134,8 @@ class ErasureCodeTpu(MatrixErasureCode):
                                       [row[i] for i in decode_index],
                                       [row[e] for e in erasures])
             mm = GFMatmul(dmat)
-            self._decode_mm.put(sig, mm, cost=self.k)
+            self._decode_mm.put(sig, mm)
         return mm(data)
-
-    def decode_batch_full(self, erasures: list[int], data,
-                          valid=None):
-        """Reconstruct `erasures` straight from the FULL chunk array —
-        device-resident survivor selection, the staging-free decode
-        path.
-
-        data: (..., k+m, N) in ARRIVAL layout (every chunk slot
-        present; erased slots carry garbage).  `valid` optionally
-        narrows which slots hold real survivor data (length-n bool
-        mask; default: everything outside `erasures`).  The decode
-        matrix is the zero-column (nerrs x n) form — the selection IS
-        the matrix — and the kernel slices the survivor rows on
-        DEVICE, so no host-side stack/moveaxis exists and only 8k
-        bit-planes unpack (see bitmatmul.GFDecodeFull).  Returns
-        (..., len(erasures), N) on device.  Kernels cached per erasure
-        signature in HBM, cost-weighted in the LRU (full-width entries
-        are (k+m)/k x a dense entry)."""
-        from ..kernels.bitmatmul import GFDecodeFull
-        from ..matrix_code import make_decode_matrix_full
-        n = self.k + self.m
-        erased = sorted(int(e) for e in erasures)
-        if valid is None:
-            valid = np.ones(n, dtype=bool)
-            valid[erased] = False
-        else:
-            valid = np.asarray(valid, dtype=bool)
-        sig = "full" + "".join(f"-{e}" for e in erased) + \
-            "+v" + "".join("1" if v else "0" for v in valid)
-        mm = self._decode_mm.get(sig)
-        if mm is None:
-            decode_index = [i for i in range(n)
-                            if valid[i] and i not in set(erased)][:self.k]
-            if len(decode_index) < self.k:
-                raise ErasureCodeError(
-                    "EIO: fewer than k valid chunks available")
-            dmat = make_decode_matrix_full(self.encode_matrix, self.k,
-                                           n, decode_index, erased)
-            mm = GFDecodeFull(dmat, valid)
-            self._decode_mm.put(sig, mm, cost=n)
-        # staging-free contract (PR 9): the kernel slices survivors on
-        # device — nothing inside this dispatch may touch the host
-        from ...common import jaxguard
-        with jaxguard.guard_transfers():
-            return mm(data)
-
-    def decode_batches_full(self, erasures: list[int], batches,
-                            valid=None):
-        """Pipelined staging-free decode over a stream of host-resident
-        full-width batches: batch i+1's H2D transfer (async
-        jax.device_put) is issued BEFORE batch i's result is consumed,
-        so the transfer of the next dispatch double-buffers against the
-        previous dispatch's kernel.  Yields device arrays in order."""
-        import jax
-        it = iter(batches)
-        try:
-            nxt = jax.device_put(next(it))
-        except StopIteration:
-            return
-        while True:
-            cur = nxt
-            out = self.decode_batch_full(erasures, cur, valid)
-            try:
-                # next batch's H2D starts while `out`'s kernel runs
-                nxt = jax.device_put(next(it))
-            except StopIteration:
-                yield out
-                return
-            yield out
 
 
 PLUGIN = ErasureCodePlugin("tpu", ErasureCodeTpu)

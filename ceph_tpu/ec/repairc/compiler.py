@@ -13,8 +13,8 @@ multiplicative identity), then lowers the whole schedule to
 
     gather survivor planes -> one grouped GF(2^8) matmul -> scatter
 
-executed through the existing device kernels (GFMatmul: Pallas on TPU
-per `ec_tpu_backend`, the XLA bit-plane matmul elsewhere) or the numpy
+executed through the existing device kernels (GFMatmul: Pallas on the
+TPU, the XLA bit-plane matmul elsewhere) or the numpy
 oracle.  Probing costs `total_planes` interpreted 1-byte-sub-chunk
 repairs per signature — paid once, then cached (see cache.py).
 """

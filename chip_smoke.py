@@ -111,8 +111,8 @@ def phase_device(n_chips: int, cache_dir: str):
 
 def phase_ec(rng, stripes: int = 256, chunk: int = 128 * 1024,
              checked: int = 16) -> None:
-    """k=8,m=4 encode and both decode forms over `stripes` 1 MiB
-    objects held in HBM; sampled stripes against the numpy isa plugin."""
+    """k=8,m=4 encode and decode over `stripes` 1 MiB objects held in
+    HBM; sampled stripes against the numpy isa plugin."""
     import jax
     import jax.numpy as jnp
     from ceph_tpu.ec import registry
@@ -125,11 +125,9 @@ def phase_ec(rng, stripes: int = 256, chunk: int = 128 * 1024,
     data = jax.device_put(data_np)
     parity, enc_c, enc_s = compile_then_steady(
         lambda: tpu.encode_batch(data))
-    chunks = jnp.concatenate([data, parity], axis=1)     # arrival layout
+    chunks = jnp.concatenate([data, parity], axis=1)
     decode_index = [i for i in range(K + M) if i not in ERASURES][:K]
     survivors = jnp.take(chunks, jnp.asarray(decode_index), axis=1)
-    full, full_c, full_s = compile_then_steady(
-        lambda: tpu.decode_batch_full(list(ERASURES), chunks))
     staged, st_c, st_s = compile_then_steady(
         lambda: tpu.decode_batch(decode_index, list(ERASURES), survivors))
 
@@ -137,7 +135,6 @@ def phase_ec(rng, stripes: int = 256, chunk: int = 128 * 1024,
                              replace=False))
     sel = jnp.asarray(idx)
     got_parity = np.asarray(jnp.take(parity, sel, axis=0))
-    got_full = np.asarray(jnp.take(full, sel, axis=0))
     got_staged = np.asarray(jnp.take(staged, sel, axis=0))
     mismatches = 0
     for row, s in enumerate(idx):
@@ -146,7 +143,6 @@ def phase_ec(rng, stripes: int = 256, chunk: int = 128 * 1024,
         want_lost = np.stack([want[e] for e in ERASURES])
         mismatches += int(not np.array_equal(got_parity[row],
                                              want_parity))
-        mismatches += int(not np.array_equal(got_full[row], want_lost))
         mismatches += int(not np.array_equal(got_staged[row], want_lost))
     mb = stripes * K * chunk / 1e6
     emit("ec", k=K, m=M, stripes=stripes, chunk_bytes=chunk,
@@ -154,7 +150,6 @@ def phase_ec(rng, stripes: int = 256, chunk: int = 128 * 1024,
          stripes_checked=len(idx), byte_mismatches=mismatches,
          use_pallas=tpu._encode_mm.use_pallas,
          encode_compile_s=enc_c, encode_steady_s=enc_s,
-         decode_full_compile_s=full_c, decode_full_steady_s=full_s,
          decode_compile_s=st_c, decode_steady_s=st_s)
     check(mismatches == 0, f"ec: {mismatches} mismatching stripe checks")
 

@@ -81,41 +81,6 @@ int oracle_do_rule(struct crush_map *m, int ruleno, int x, int *result,
 	return n;
 }
 
-/* Bulk single-threaded mapping loop: the baseline timing surface for
- * PLACEMENT_BENCH's vs_baseline (the osdmaptool --test-map-pgs
- * workload on one core; the reference threads this via
- * ParallelPGMapper, src/osd/OSDMapMapping.h).  Workspace allocated
- * once; returns an output checksum so the loop cannot be elided. */
-long long oracle_map_bulk(struct crush_map *m, int ruleno,
-			  const int *xs, int n, int result_max,
-			  unsigned *weights, int weight_max,
-			  int *out)
-{
-	int result[64];
-	char *work = malloc(crush_work_size(m, result_max));
-	long long acc = 0;
-	int i, j, cnt;
-
-	if (result_max > 64)
-		result_max = 64;
-	for (i = 0; i < n; i++) {
-		crush_init_workspace(m, work);
-		cnt = crush_do_rule(m, ruleno, xs[i], result, result_max,
-				    weights, weight_max, work, NULL);
-		for (j = 0; j < cnt; j++) {
-			acc += result[j];
-			if (out)
-				out[(long long)i * result_max + j] =
-					result[j];
-		}
-		if (out)
-			for (; j < result_max; j++)
-				out[(long long)i * result_max + j] = -1;
-	}
-	free(work);
-	return acc;
-}
-
 unsigned oracle_hash32_2(unsigned a, unsigned b)
 {
 	return crush_hash32_2(CRUSH_HASH_RJENKINS1, a, b);

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """jaxguard smoke — the device-contract half of the ship gate.
 
-One batched EC encode/decode pair (the staged path through
-osd/ecutil plus the PR 9 staging-free decode_batch_full path) run
+One batched EC encode/decode pair through osd/ecutil (the served
+path: encode_batch and decode_batch inside the transfer guard) run
 TWICE with identical shapes, asserting:
 
 * **exactly-once compilation per signature**: every jit callsite's
@@ -43,25 +43,13 @@ def total_compiles(st):
 
 
 def one_pair(ec, sinfo, data):
-    """One batched encode + staged decode + staging-free full decode."""
+    """One batched encode, then a decode of one data and one parity
+    shard from the survivors, both through ecutil."""
     shards = ecutil.encode(sinfo, ec, data)
     have = {i: shards[i] for i in range(K + M) if i not in (1, K)}
     got = ecutil.decode(sinfo, ec, have, want=[1, K])
     assert got[1] == shards[1] and got[K] == shards[K], \
         "decode mismatch"
-    # staging-free decode: (S, k+m, N) arrival layout, erased slots
-    # carrying garbage the zero-column matrix must ignore
-    cs = sinfo.chunk_size
-    arrival = np.zeros((STRIPES, K + M, cs), dtype=np.uint8)
-    for i in range(K + M):
-        if i in (1, K):
-            arrival[:, i, :] = 0xAB     # garbage in the erased slots
-        else:
-            arrival[:, i, :] = np.frombuffer(
-                shards[i], dtype=np.uint8).reshape(STRIPES, cs)
-    rec = np.asarray(ec.decode_batch_full([1, K], arrival))
-    assert rec[:, 0, :].tobytes() == shards[1], "full decode mismatch"
-    assert rec[:, 1, :].tobytes() == shards[K], "full decode mismatch"
 
 
 def main() -> int:
@@ -103,8 +91,7 @@ def main() -> int:
     sites = sum(1 for v in st2.values() if v["calls"])
     print(f"jaxguard smoke: OK ({sites} jit callsites, "
           f"{total_compiles(st2)} compiles, all exactly-once per "
-          f"signature; transfer guard clean on encode/decode/"
-          f"decode_batch_full)")
+          f"signature; transfer guard clean on encode/decode)")
     return 0
 
 

@@ -34,7 +34,9 @@ def test_asymmetric_partition_quorum_excludes_half_blind_mon():
         # a -> b blocked only: mon.2 is deaf, not mute
         ids = c.network.faults.partition(
             ["mon.0", "mon.1"], ["mon.2"], symmetric=False)
-        now = 50_000.0
+        # the fake clock must run ahead of the real one that stamped
+        # set-up, whatever the host's uptime
+        now = time.monotonic() + 50_000.0
         epochs = []
         for i in range(10):
             now += 11.0

@@ -1,5 +1,5 @@
 """chip_smoke.py: its phases at a tiny size on the CPU, and its refusal
-to run at all without a TPU (so does bench.py)."""
+to run at all without a TPU."""
 import json
 import os
 import subprocess
@@ -20,7 +20,7 @@ def last_line(capsys, phase):
     return line
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+@pytest.mark.parametrize("script", ["chip_smoke.py"])
 def test_refuses_to_run_without_a_tpu(script):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run([sys.executable, script], cwd=ROOT, env=env,

@@ -1,6 +1,7 @@
 """The EC kernels of the main path compile for a described v5e chip at
-bench widths (k=8, m=4, 256 stripes x 128 KiB chunks), and where the
-persistent compile cache lives.
+the widths chip_smoke.py and the benchmark serve (k=8, m=4: 256
+stripes x 128 KiB chunks, and a degraded 4 MiB read's 128 stripes x
+4 KiB), and where the persistent compile cache lives.
 
 Compiling needs no chip, only the TPU compiler; nothing runs, so this
 says nothing of results or times.  The topology is described inside a
@@ -9,17 +10,13 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from ceph_tpu.common import compile_cache
-from ceph_tpu.ec import gf
 from ceph_tpu.ec.kernels import bitmatmul as bm
-from ceph_tpu.ec.matrix_code import make_decode_matrix_full
 
 K, M = 8, 4
 STRIPES, CHUNK = 256, 128 * 1024
-ENC = gf.isa_rs_matrix(K, M)
 
 
 @pytest.fixture(scope="module")
@@ -47,29 +44,19 @@ def shape(one_chip, shp, dtype):
     return jax.ShapeDtypeStruct(shp, dtype, sharding=one_chip)
 
 
-@pytest.mark.parametrize("group,tile", [(4, 8192), (1, 2048)])
-def test_encode_kernel_compiles(one_chip, group, tile):
-    bgp = shape(one_chip, (8 * M * group, 8 * K * group), jnp.int8)
-    data = shape(one_chip, (STRIPES, K, CHUNK), jnp.uint8)
+@pytest.mark.parametrize("rows,stripes,chunk,group,tile", [
+    pytest.param(M, STRIPES, CHUNK, 4, 8192, id="4-8192"),
+    pytest.param(M, STRIPES, CHUNK, 1, 2048, id="1-2048"),
+    # one lost data chunk of ec-k8m4.read-degraded-4m: the (1 x 8)
+    # decode matrix over a 4 MiB object's 128 stripes
+    pytest.param(1, 128, 4096, 4, 2048, id="decode-1x8"),
+])
+def test_encode_kernel_compiles(one_chip, rows, stripes, chunk, group,
+                                tile):
+    bgp = shape(one_chip, (8 * rows * group, 8 * K * group), jnp.int8)
+    data = shape(one_chip, (stripes, K, chunk), jnp.uint8)
     compiled = bm.gf_matmul_pallas_grouped.lower(
         bgp, data, group=group, tile_n=tile).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_full_decode_kernel_compiles(one_chip):
-    erasures = [1, 9]
-    valid = np.ones(K + M, dtype=bool)
-    valid[erasures] = False
-    survivors = [i for i in range(K + M) if valid[i]][:K]
-    full = make_decode_matrix_full(ENC, K, K + M, survivors, erasures)
-    sel = tuple(bm.selection_from_matrix(full, valid))
-    group = 4
-    bgp = shape(one_chip, (8 * len(erasures) * group, 8 * K * group),
-                jnp.int8)
-    data = shape(one_chip, (STRIPES, K + M, CHUNK), jnp.uint8)
-    compiled = bm.gf_decode_pallas_grouped_full.lower(
-        bgp, data, sel=sel, n=K + M, group=group,
-        tile_n=8192).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 

@@ -95,28 +95,24 @@ def test_ragged_tail_sizes():
                               gf.gf_matmul_bytes(mat, data))
 
 
-def test_decode_batch_full_matches_gathered():
-    """Device-resident survivor selection: the zero-column full-width
-    decode matrix reconstructs identically to the gathered decode path,
-    and garbage in erased slots is ignored."""
-    import numpy as np
-    from ceph_tpu.ec import registry
+def test_ecutil_decode_from_shard_bytes():
+    """The served decode: ecutil.decode from a dict of per-shard bytes
+    (what a primary holds after its sub-reads) rebuilds the erased
+    shards' chunk streams exactly."""
+    from ceph_tpu.osd import ecutil
     tpu = registry.factory("tpu", {"k": "4", "m": "2"})
     rng = np.random.default_rng(42)
-    data = rng.integers(0, 256, (6, 4, 512), dtype=np.uint8)
-    parity = np.asarray(tpu.encode_batch(data))
-    chunks = np.concatenate([data, parity], axis=1)        # (S, 6, N)
+    stripes, cs = 6, 512
+    sinfo = ecutil.StripeInfo(4, 4 * cs)
+    data = rng.integers(0, 256, stripes * 4 * cs, dtype=np.uint8)
+    from ceph_tpu.ec import gf
+    stripe_chunks = data.reshape(stripes, 4, cs)
+    chunks = np.stack([np.concatenate(
+        [d, gf.gf_matmul_bytes(tpu.encode_matrix[4:], d)])
+        for d in stripe_chunks])                           # (S, 6, cs)
+    shards = {i: chunks[:, i, :].tobytes() for i in range(6)}
     for erasures in ([1], [0, 5], [2, 3]):
-        full = chunks.copy()
+        have = {i: b for i, b in shards.items() if i not in erasures}
+        got = ecutil.decode(sinfo, tpu, have, want=erasures)
         for e in erasures:
-            full[:, e] = rng.integers(0, 256, full[:, e].shape,
-                                      dtype=np.uint8)      # garbage
-        rec = np.asarray(tpu.decode_batch_full(erasures, full))
-        decode_index = [i for i in range(6)
-                        if i not in set(erasures)][:4]
-        survivors = chunks[:, decode_index, :]
-        want = np.asarray(tpu.decode_batch(decode_index, list(erasures),
-                                           survivors))
-        assert np.array_equal(rec, want)
-        for j, e in enumerate(sorted(erasures)):
-            assert np.array_equal(rec[:, j], chunks[:, e])
+            assert got[e] == shards[e], (erasures, e)

@@ -18,6 +18,7 @@ from typing import Optional
 
 from ..common.log import dout
 from ..common.options import global_config
+from ..common.perf_counters import global_perf
 from ..msg.messages import (MAuthReply, MGR_UNAVAILABLE_EAGAIN, MMap,
                             MMonCommand, MMonCommandAck,
                             MMonSubscribe, MWatchNotify, OSDOp,
@@ -114,6 +115,12 @@ class Objecter(Dispatcher, MonHunter):
         # (ref: Objecter::LingerOp — watches re-register when the
         # object's primary moves)
         self.watches: dict[str, dict] = {}
+        # PG -> acting primary for the map epoch in _targets_epoch: a
+        # CRUSH evaluation costs milliseconds of interpreter time, and
+        # its answer changes only with the map.  Filled lazily, one
+        # entry per folded PG; any other epoch misses
+        self._targets: dict[PG, int] = {}
+        self._targets_epoch = -1
         self._rescan_timer = None
         self._pending_cmds: dict = {}
         #: non-threaded harnesses set this to a network pump callable;
@@ -128,6 +135,11 @@ class Objecter(Dispatcher, MonHunter):
         self.ms = Messenger.create(network, self.name, threaded=threaded)
         self.ms.add_dispatcher(self)
         self.ms.tracer = self.tracer
+        # target-table counters (ref: the l_osdc_* logger in
+        # Objecter::init), one logger per client
+        self.perf = global_perf().create(f"{self.name}.objecter")
+        for key in ("op_target_hits", "op_target_misses"):
+            self.perf.add_u64_counter(key)
 
     # ------------------------------------------------------------ setup
     def start(self) -> None:
@@ -141,6 +153,7 @@ class Objecter(Dispatcher, MonHunter):
 
     def shutdown(self) -> None:
         self.ms.shutdown()
+        global_perf().remove(self.perf.name)
 
     def wait_sync(self, done, timeout: float, ev=None) -> bool:
         """Wait for `done()` — blocking on `ev` (default: the map
@@ -254,6 +267,8 @@ class Objecter(Dispatcher, MonHunter):
         with self._lock:
             self.osdmap = self.osdmap.ingest(msg.full_map,
                                              msg.incrementals)
+            self._targets = {}
+            self._targets_epoch = self.osdmap.epoch
             dout("client", 10).write("%s: osdmap e%d", self.name,
                                      self.osdmap.epoch)
             self._scan_requests()
@@ -297,21 +312,37 @@ class Objecter(Dispatcher, MonHunter):
     # ------------------------------------------------------ target calc
     def _calc_target(self, op: _Op) -> None:
         """(ref: Objecter.cc:1095 _calc_target)."""
-        try:
-            if op.pg_ps is not None:
-                raw = PG(op.pool, op.pg_ps)
-                if op.pool not in self.osdmap.pools:
-                    raise KeyError(op.pool)
-            else:
-                raw = self.osdmap.object_locator_to_pg(op.oid, op.pool)
-        except KeyError:
-            op.pg, op.target_osd = None, -1
-            return
-        pool = self.osdmap.pools[op.pool]
-        op.pg = pool.raw_pg_to_pg(raw)
-        _, _, _, acting_primary = self.osdmap.pg_to_up_acting_osds(raw)
-        op.target_osd = acting_primary if acting_primary >= 0 and \
-            self.osdmap.is_up(acting_primary) else -1
+        op.pg, primary = self._pg_primary(op.pool, op.oid, op.pg_ps)
+        op.target_osd = primary if primary >= 0 and \
+            self.osdmap.is_up(primary) else -1
+
+    def _pg_primary(self, pool_id: int, oid: str,
+                    pg_ps: Optional[int] = None
+                    ) -> tuple[Optional[PG], int]:
+        """The folded PG of an object (or of a PG-addressed op's
+        `pg_ps`) and its acting primary at the current epoch; (None,
+        -1) when the pool is gone.  The primary comes from the epoch's
+        target table.  Keying it on the folded PG is exact: the
+        placement seed folds by pgp_num <= pg_num, and the stable mod
+        composes, while pg_temp, primary_temp and upmap are all looked
+        up by the folded PG."""
+        pool = self.osdmap.pools.get(pool_id)
+        if pool is None:
+            return None, -1
+        raw = PG(pool_id, pg_ps) if pg_ps is not None else \
+            self.osdmap.object_locator_to_pg(oid, pool_id)
+        pg = pool.raw_pg_to_pg(raw)
+        if self._targets_epoch != self.osdmap.epoch:
+            self._targets = {}
+            self._targets_epoch = self.osdmap.epoch
+        primary = self._targets.get(pg)
+        if primary is None:
+            self.perf.inc("op_target_misses")
+            primary = self._targets[pg] = \
+                self.osdmap.pg_to_up_acting_osds(pg)[3]
+        else:
+            self.perf.inc("op_target_hits")
+        return pg, primary
 
     # -------------------------------------------------------- op submit
     def submit(self, pool: int, oid: str, op: str, offset: int = 0,
@@ -481,11 +512,8 @@ class Objecter(Dispatcher, MonHunter):
         re-establishes it like the reference's linger resend
         (Objecter::_linger_submit on map change)."""
         for cookie, w in list(self.watches.items()):
-            try:
-                raw = self.osdmap.object_locator_to_pg(w["oid"],
-                                                       w["pool"])
-                _, _, _, primary = self.osdmap.pg_to_up_acting_osds(raw)
-            except KeyError:
+            pg, primary = self._pg_primary(w["pool"], w["oid"])
+            if pg is None:
                 continue
             if primary < 0 or not self.osdmap.is_up(primary):
                 # no live primary: whoever comes back (even the same

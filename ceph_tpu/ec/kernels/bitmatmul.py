@@ -174,31 +174,36 @@ def pack_matrix(rows: int) -> np.ndarray:
 
 
 def _gf_kernel_planar(bitmat_ref, pack_ref, data_ref, out_ref):
-    """One (stripe-group, N-tile) cell: plane-major unpack -> dense-tile
-    MXU matmul -> &1 -> MXU pack-matmul; bit-planes only in VMEM."""
-    data = data_ref[0].astype(jnp.int32)           # (gk, TN)
-    planes = [((data >> c) & 1) for c in range(8)]
-    bits = jnp.concatenate(planes, axis=0).astype(jnp.int8)  # (8gk, TN)
-    acc = jax.lax.dot_general(
-        bitmat_ref[...], bits, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)          # (8gr, TN)
-    acc1 = (acc & 1).astype(jnp.int8)
-    packed = jax.lax.dot_general(
-        pack_ref[...], acc1, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)          # (gr, TN)
-    out_ref[0] = packed.astype(jnp.uint8)
+    """One (stripe-group block, N-tile) cell, per stripe group of the
+    block: plane-major unpack -> dense-tile MXU matmul -> &1 -> MXU
+    pack-matmul; bit-planes only in VMEM."""
+    for i in range(data_ref.shape[0]):
+        data = data_ref[i].astype(jnp.int32)           # (gk, TN)
+        planes = [((data >> c) & 1) for c in range(8)]
+        bits = jnp.concatenate(planes, axis=0).astype(jnp.int8)  # (8gk, TN)
+        acc = jax.lax.dot_general(
+            bitmat_ref[...], bits, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32)          # (8gr, TN)
+        acc1 = (acc & 1).astype(jnp.int8)
+        packed = jax.lax.dot_general(
+            pack_ref[...], acc1, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32)          # (gr, TN)
+        out_ref[i] = packed.astype(jnp.uint8)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("group", "tile_n", "interpret"))
+                   static_argnames=("group", "tile_n", "interpret",
+                                    "block"))
 def gf_matmul_pallas_grouped(bitmat_gp: jax.Array, data: jax.Array,
                              group: int, tile_n: int,
-                             interpret: bool = False) -> jax.Array:
-    """Fused grouped kernel: grid (stripe-groups, N-tiles); the grid
-    walks the stripe axis directly (no batch flatten/transpose).
+                             interpret: bool = False,
+                             block: int = 1) -> jax.Array:
+    """Fused grouped kernel: grid (stripe-group blocks, N-tiles); the
+    grid walks the stripe axis directly (no batch flatten/transpose),
+    `block` stripe groups per cell, which the kernel takes in turn.
 
     bitmat_gp: grouped_planar_bitmatrix; data (S, k, N) uint8 with
-    S % group == 0 and N % tile_n == 0."""
+    S % (group * block) == 0 and N % tile_n == 0."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -210,16 +215,17 @@ def gf_matmul_pallas_grouped(bitmat_gp: jax.Array, data: jax.Array,
     out = pl.pallas_call(
         _gf_kernel_planar,
         out_shape=jax.ShapeDtypeStruct((s // group, gr, n), jnp.uint8),
-        grid=(s // group, n // tile_n),
+        grid=(s // group // block, n // tile_n),
         in_specs=[
             pl.BlockSpec((gr8, gk8), lambda i, j: (0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((gr, gr8), lambda i, j: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, gk, tile_n), lambda i, j: (i, 0, j),
+            pl.BlockSpec((block, gk, tile_n), lambda i, j: (i, 0, j),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, gr, tile_n), lambda i, j: (i, 0, j),
+        out_specs=pl.BlockSpec((block, gr, tile_n),
+                               lambda i, j: (i, 0, j),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
     )(bitmat_gp, pmat, d)
@@ -228,32 +234,62 @@ def gf_matmul_pallas_grouped(bitmat_gp: jax.Array, data: jax.Array,
 
 PALLAS_MIN_TILE = 2048
 PALLAS_TILE = 8192
+#: the widest contraction (8 x input rows x stripe group) a stripe
+#: group is grown to: two passes of the v5e's 128-deep MXU; past it a
+#: group adds only the block-diagonal's zeros
+PALLAS_MAX_CONTRACTION = 256
+#: the lane granule: a stripe narrower than PALLAS_MIN_TILE takes the
+#: kernel whole only at a multiple of it
+LANE = 128
+
+
+def pallas_layout(s: int, k: int, n: int) -> tuple[int, int, int]:
+    """(group, tile, block) of the fused kernel for s stripes of k input
+    rows by n lanes: `group` stripes share one block-diagonal matmul (4,
+    2 or 1 dividing s, and no more than keep its contraction 8*k*group
+    within PALLAS_MAX_CONTRACTION); N tiles of `tile` lanes; `block`
+    stripe groups per grid cell, above 1 only where a stripe's lanes
+    are narrower than PALLAS_MIN_TILE (a sub-chunk code's planes), so
+    that a cell still takes up to PALLAS_TILE lanes.  tile 0: no lane
+    prefix the kernel takes (the XLA path serves it whole); a tile
+    that does not divide n leaves a ragged tail to the XLA path."""
+    group = next(g for g in (4, 2, 1)
+                 if s % g == 0 and (8 * k * g <= PALLAS_MAX_CONTRACTION
+                                    or g == 1))
+    if n % PALLAS_TILE == 0:
+        return group, PALLAS_TILE, 1
+    if n >= PALLAS_MIN_TILE:
+        return group, PALLAS_MIN_TILE, 1
+    if n % LANE:
+        return group, 0, 1
+    groups = s // group
+    block = max(b for b in range(1, PALLAS_TILE // n + 1)
+                if groups % b == 0)
+    return group, n, block
 
 
 def gf_matmul_pallas(mat: np.ndarray, data: jax.Array,
                      interpret: bool = False) -> jax.Array:
-    """Fused-kernel entry on the BYTE matrix `mat` (r, k): picks the
-    stripe group (4/2/1 dividing the batch) and N tiling, sends ragged
-    tails through the XLA path.  data (..., k, N) -> (..., r, N)."""
+    """Fused-kernel entry on the BYTE matrix `mat` (r, k): the layout of
+    pallas_layout; ragged tails go through the XLA path.
+    data (..., k, N) -> (..., r, N)."""
     mat = np.ascontiguousarray(mat, dtype=np.uint8)
     r, k = mat.shape
     *lead, k_, n = data.shape
     s = int(np.prod(lead)) if lead else 1
     d = data.reshape(s, k, n)
-    group = 4 if s % 4 == 0 else 2 if s % 2 == 0 else 1
-    tile = PALLAS_TILE if n % PALLAS_TILE == 0 else (
-        PALLAS_MIN_TILE if n % PALLAS_MIN_TILE == 0 else 0)
-    body_n = n if tile else (n // PALLAS_MIN_TILE) * PALLAS_MIN_TILE
+    group, tile, block = pallas_layout(s, k, n)
+    body_n = n - n % tile if tile else 0
     if body_n == 0:
         B = jnp.asarray(companion_bitmatrix(mat.tobytes(), r, k))
         return gf_matmul_xla(B, data)
     bgp = jnp.asarray(grouped_planar_bitmatrix(mat.tobytes(), r, k, group))
-    if tile:
+    if body_n == n:
         out = gf_matmul_pallas_grouped(bgp, d, group=group, tile_n=tile,
-                                       interpret=interpret)
+                                       interpret=interpret, block=block)
     else:
         body = gf_matmul_pallas_grouped(
-            bgp, d[:, :, :body_n], group=group, tile_n=PALLAS_MIN_TILE,
+            bgp, d[:, :, :body_n], group=group, tile_n=tile,
             interpret=interpret)
         B = jnp.asarray(companion_bitmatrix(mat.tobytes(), r, k))
         tail = gf_matmul_xla(B, d[:, :, body_n:])

@@ -113,6 +113,18 @@ def make_decode_matrix(encode_matrix: np.ndarray, k: int,
     return np.stack(rows).astype(np.uint8)
 
 
+def byte_matrix_code(ec) -> bool:
+    """Whether a code is a GF(2^8) matrix code over whole bytes: then
+    one byte of each chunk, probed, gives its map at any chunk size
+    (not so for wide-word fields, GF(2) bitmatrix packets or
+    sub-chunks).  The lrc and clay plugins take the batched device
+    path only over such codes."""
+    from .plugins.jerasure import Bitmatrix
+    return (isinstance(ec, MatrixErasureCode) and ec.field is None
+            and not isinstance(ec, Bitmatrix)
+            and ec.get_sub_chunk_count() == 1)
+
+
 class MatrixErasureCode(ErasureCode):
     """Systematic MDS matrix code with pluggable matmul.
 

@@ -21,6 +21,18 @@ Structure mirrors the reference exactly:
 Buffers are numpy arrays; sub-chunk views are numpy slices, so the
 "bufferlist substr_of" aliasing of the C++ (transform writes land in
 the parent chunk) holds naturally.
+
+When the scalar MDS and pairwise codes are GF(2^8) matrix codes over
+whole bytes (every jerasure and isa technique but the bit-matrix
+liber8tion; reed_sol_van is the default), the whole code is one
+GF(2^8)-linear map of (shard, plane) sub-chunks per signature, so the
+plugin has the batched device API (`encode_batch`/`decode_batch`, as
+lrc): the generator and each decode signature's matrix (for one lost
+chunk, the d-helper repair plan recovery runs) are lowered from this
+interpreted arithmetic by basis probes, one byte per plane
+(ceph_tpu.ec.repairc), and run as one GFMatmul dispatch each over the
+planes, plane p of a chunk being its bytes [p * chunk / q^t,
+(p + 1) * chunk / q^t).
 """
 from __future__ import annotations
 
@@ -30,6 +42,7 @@ import numpy as np
 
 from ..interface import (ErasureCode, ErasureCodeError, ErasureCodeProfile,
                          sanity_check_k_m, to_int)
+from ..matrix_code import byte_matrix_code
 from ..registry import ErasureCodePlugin
 
 
@@ -83,6 +96,10 @@ class ErasureCodeClay(ErasureCode):
                                     self.mds_profile)
         self.pft = registry.factory(self.pft_profile["plugin"],
                                     self.pft_profile)
+        if not (byte_matrix_code(self.mds) and byte_matrix_code(self.pft)):
+            # no byte probe can lower the code: the profile has no
+            # batched API, and ecutil takes the per-stripe path
+            self.encode_batch = self.decode_batch = None
 
     def parse(self, profile: ErasureCodeProfile) -> None:
         """ref: ErasureCodeClay.cc:190-302."""
@@ -479,6 +496,38 @@ class ErasureCodeClay(ErasureCode):
             i3: self.U_buf[node_sw][z_sw * sc_size:(z_sw + 1) * sc_size],
         }
         self.pft.decode_chunks({2, 3}, coupled, pft_sub)
+
+    # -- batched device API (ecutil's one-dispatch path) ---------------------
+    def batch_program(self, survivors=(), erasures=()) -> tuple:
+        """(program, whether this call built it) for an encode (no
+        arguments) or for the decode of `erasures` from `survivors`,
+        each ascending, from the plugin's RepairProgramCache, which
+        recovery's programs share.  One lost chunk with d helpers for
+        survivors decodes by the repair plan recovery runs
+        (repair_schedule: q^(t-1) repair planes of each helper); any
+        other signature from every plane of the survivors.  Both are
+        lowered from this plugin's own arithmetic by basis probes."""
+        from ..repairc import RepairPlan, batch_program
+        if not erasures:
+            return batch_program(self)
+        plan = self.repair_schedule(set(erasures), set(survivors)) or \
+            RepairPlan.make(erasures,
+                            {s: [(0, self.sub_chunk_no)] for s in survivors},
+                            sub_chunk_no=self.sub_chunk_no)
+        return batch_program(self, plan)
+
+    def encode_batch(self, data):
+        """(..., k * q^t, N) data planes (plane p of data chunk j at row
+        j * q^t + p) -> (..., m * q^t, N) coding planes, on device: one
+        GF(2^8) matmul over the probed generator."""
+        return self.batch_program()[0].kernel(data)
+
+    def decode_batch(self, survivors: list[int], erasures: list[int], data):
+        """(..., planes, N): the planes minimum_to_decode names of the
+        ascending `survivors`, each survivor's in its extents' order ->
+        (..., len(erasures) * q^t, N) planes of the rebuilt chunks, on
+        device: one GF(2^8) matmul per erasure signature."""
+        return self.batch_program(survivors, erasures)[0].kernel(data)
 
     # -- single-chunk repair -------------------------------------------------
     def repair(self, want_to_read: set, chunks: Mapping[int, np.ndarray],

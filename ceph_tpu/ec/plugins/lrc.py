@@ -32,7 +32,7 @@ import numpy as np
 
 from ..interface import (ErasureCode, ErasureCodeError, ErasureCodeProfile,
                          to_int)
-from ..matrix_code import MatrixErasureCode
+from ..matrix_code import byte_matrix_code
 from ..registry import ErasureCodePlugin
 
 DEFAULT_KML = -1
@@ -56,17 +56,6 @@ class Step:
     op: str
     type: str
     n: int
-
-
-def _byte_matrix_code(ec) -> bool:
-    """Whether a layer's code is a GF(2^8) matrix code over whole bytes:
-    then one byte of each chunk, probed, gives its map at any chunk
-    size (not so for wide-word fields, GF(2) bitmatrix packets or
-    sub-chunks)."""
-    from .jerasure import Bitmatrix
-    return (isinstance(ec, MatrixErasureCode) and ec.field is None
-            and not isinstance(ec, Bitmatrix)
-            and ec.get_sub_chunk_count() == 1)
 
 
 def _json_loads(s: str):
@@ -134,7 +123,7 @@ class ErasureCodeLrc(ErasureCode):
         self.data_chunk_count_ = mapping.count("D")
         self.chunk_count_ = len(mapping)
         self.layers_sanity_checks(layers_str)
-        if not all(_byte_matrix_code(layer.erasure_code)
+        if not all(byte_matrix_code(layer.erasure_code)
                    for layer in self.layers):
             # no byte probe can lower such a layer: the profile has no
             # batched API, and ecutil takes the per-stripe path
@@ -519,21 +508,11 @@ class ErasureCodeLrc(ErasureCode):
         own arithmetic by basis probes: the generator through
         encode_chunks, a decode signature's matrix through decode_chunks
         (the signature's key is its RepairPlan's)."""
-        from ..repairc import (RepairPlan, cache_of, compile_encode,
-                               compile_program)
-
-        def build(compile_, *args):
-            prog = compile_(self, *args)
-            prog.kernel                 # the device kernel, with it
-            return prog
-        cache = cache_of(self)
+        from ..repairc import RepairPlan, batch_program
         if not erasures:
-            return cache.get_or_build("encode",
-                                      lambda: build(compile_encode))
-        plan = RepairPlan.make(erasures, {s: [(0, 1)] for s in survivors},
-                               sub_chunk_no=1)
-        return cache.get_or_build(plan.signature(),
-                                  lambda: build(compile_program, plan))
+            return batch_program(self)
+        return batch_program(self, RepairPlan.make(
+            erasures, {s: [(0, 1)] for s in survivors}, sub_chunk_no=1))
 
     def encode_batch(self, data):
         """(..., k, N) data chunks in mapping order -> (..., n-k, N)

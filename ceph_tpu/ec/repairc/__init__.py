@@ -15,7 +15,9 @@ available)` interface hook (ceph_tpu/ec/interface.py); `None` means
 "no partial plan for this signature" and callers fall back to
 wholesale full-chunk recovery.  The lrc plugin's client path lowers
 its encode (`compile_encode`) and its degraded-read decodes the same
-way, into the same cache.
+way, into the same cache, and so does the clay plugin's: its encode
+and degraded-read decodes, the latter through the same d-helper repair
+plans recovery runs.
 
 Motivated by schedule-level XOR program compilation (arxiv
 2108.02692) and the LRC rebuild-time results of arxiv 1906.08602.
@@ -23,8 +25,8 @@ Motivated by schedule-level XOR program compilation (arxiv
 from .plan import RepairPlan
 from .compiler import (RepairProgram, compile_encode, compile_program,
                        interpret_plan)
-from .cache import RepairProgramCache, program_for, cache_of
+from .cache import RepairProgramCache, batch_program, program_for, cache_of
 
 __all__ = ["RepairPlan", "RepairProgram", "RepairProgramCache",
-           "compile_encode", "compile_program", "interpret_plan",
-           "program_for", "cache_of"]
+           "batch_program", "compile_encode", "compile_program",
+           "interpret_plan", "program_for", "cache_of"]

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 from ...common.lockdep import make_lock
 from ..matrix_code import DecodeTableCache
-from .compiler import compile_program
+from .compiler import compile_encode, compile_program
 from .plan import RepairPlan
 
 #: default capacity in matrix bytes — ~256 full double-erasure
@@ -31,6 +31,7 @@ class RepairProgramCache:
     def __init__(self, capacity: int = DEFAULT_CAPACITY):
         self._lru = DecodeTableCache(capacity)
         self._lock = make_lock("ec.repairc.stats")
+        self._build = make_lock("ec.repairc.build")
         self._compiles: dict[str, int] = {}
         self._hits = 0
 
@@ -42,17 +43,23 @@ class RepairProgramCache:
 
     def get_or_build(self, key: str, build) -> tuple:
         """(program under `key`, whether this call built it): the
-        cached one, else `build()`'s, stored."""
+        cached one, else `build()`'s, stored.  One build at a time: a
+        second miss on a key waits for the first's program, and no
+        two probe runs share the plugin's interpreted state."""
         prog = self._lru.get(key)
-        if prog is not None:
-            with self._lock:
-                self._hits += 1
-            return prog, False
-        prog = build()
-        self._lru.put(key, prog, cost=prog.cost())
+        if prog is None:
+            with self._build:
+                prog = self._lru.get(key)
+                if prog is None:
+                    prog = build()
+                    self._lru.put(key, prog, cost=prog.cost())
+                    with self._lock:
+                        self._compiles[key] = \
+                            self._compiles.get(key, 0) + 1
+                    return prog, True
         with self._lock:
-            self._compiles[key] = self._compiles.get(key, 0) + 1
-        return prog, True
+            self._hits += 1
+        return prog, False
 
     def get_or_compile(self, ec, plan: RepairPlan):
         return self.get_or_build(plan.signature(),
@@ -78,6 +85,20 @@ def cache_of(ec) -> RepairProgramCache:
                 cache = RepairProgramCache()
                 ec._repairc_cache = cache
     return cache
+
+
+def batch_program(ec, plan: RepairPlan | None = None) -> tuple:
+    """(program, whether this call built it) of a plugin's batched
+    device API, from its cache, with its device kernel: the generator
+    (`plan` None, key "encode", compile_encode) or the decode of
+    `plan`'s signature (compile_program)."""
+    def build():
+        prog = compile_encode(ec) if plan is None \
+            else compile_program(ec, plan)
+        prog.kernel                 # the device kernel, with it
+        return prog
+    return cache_of(ec).get_or_build(
+        "encode" if plan is None else plan.signature(), build)
 
 
 def program_for(ec, plan: RepairPlan):

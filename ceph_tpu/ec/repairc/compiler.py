@@ -81,29 +81,32 @@ def compile_program(ec, plan: RepairPlan) -> "RepairProgram":
 def compile_encode(ec) -> "RepairProgram":
     """Derive the code's generator, its coding shards as a GF-linear map
     of its data shards, by basis probes through the plugin's own
-    encode_chunks at chunk size 1 (data shard j := 0x01, the others
-    zero, yields column j).  Probing decode cannot stand in for it: a
-    layered (lrc) decode walk need not rebuild every coding shard from
-    the data shards alone.  The program's plan names the coding shards
-    as `lost` and the data shards as helpers, both ascending, which is
-    the order of the code's chunk mapping."""
+    encode_chunks at one byte per sub-chunk plane: at chunk size
+    sub_chunk_count, data shard j's plane p := 0x01, the others zero,
+    yields column j * sub_chunk_count + p.  Probing decode cannot stand
+    in for it: a layered (lrc) decode walk need not rebuild every
+    coding shard from the data shards alone.  The program's plan names
+    the coding shards as `lost` and the data shards, whole, as helpers,
+    both ascending, which is the order of the code's chunk mapping."""
     k = ec.get_data_chunk_count()
     n = ec.get_chunk_count()
+    sub = ec.get_sub_chunk_count()
     plan = RepairPlan.make([ec.chunk_index(i) for i in range(k, n)],
-                           {ec.chunk_index(i): [(0, 1)] for i in range(k)},
-                           sub_chunk_no=1)
+                           {ec.chunk_index(i): [(0, sub)] for i in range(k)},
+                           sub_chunk_no=sub)
 
-    def probe(shard=None) -> np.ndarray:
-        encoded = {s: np.zeros(1, dtype=np.uint8) for s in range(n)}
+    def probe(shard=None, plane=0) -> np.ndarray:
+        encoded = {s: np.zeros(sub, dtype=np.uint8) for s in range(n)}
         if shard is not None:
-            encoded[shard][0] = 1
+            encoded[shard][plane] = 1
         ec.encode_chunks(set(range(n)), encoded)
-        return np.array([encoded[c][0] for c in plan.lost], dtype=np.uint8)
+        return np.concatenate([encoded[c] for c in plan.lost])
 
     if probe().any():
         raise ErasureCodeError("repairc: encode is not GF-linear "
                                "(zero data encoded non-zero parity)")
-    mat = np.stack([probe(h) for h in plan.helper_ids()], axis=1)
+    mat = np.stack([probe(h, p) for h in plan.helper_ids()
+                    for p in range(sub)], axis=1)
     return RepairProgram(plan, mat)
 
 
@@ -126,8 +129,8 @@ class RepairProgram:
     @property
     def kernel(self):
         """The device kernel (GFMatmul) over this program's matrix:
-        (..., total_planes, N) -> (..., output_planes, N) for whole-chunk
-        (sub_chunk_no 1) programs."""
+        (..., total_planes, N) -> (..., output_planes, N), N bytes of
+        each plane."""
         if self._kernel is None:
             from ..kernels.bitmatmul import GFMatmul
             self._kernel = GFMatmul(self.matrix)

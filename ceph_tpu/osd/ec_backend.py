@@ -641,11 +641,14 @@ class ECBackend:
     #: recovery_bytes_rebuilt (chunk bytes pushed to targets), which
     #: proves the sub-chunk repair saving; each ecutil device dispatch
     #: with its host->device and device->host bytes; the client decodes
-    #: whose inputs lie in one local layer (lrc); and the per-signature
-    #: programs the dispatches built (lrc)
+    #: whose inputs lie in one local layer (lrc); the per-signature
+    #: programs the dispatches built (lrc, clay); and the ecutil calls
+    #: that ran the per-stripe host loop instead (plugins without the
+    #: batched API)
     PERF_KEYS = ("recovery_bytes_read", "recovery_bytes_rebuilt",
                  "ec_dispatches", "ec_h2d_bytes", "ec_d2h_bytes",
-                 "ec_local_decodes", "ec_programs_built")
+                 "ec_local_decodes", "ec_programs_built",
+                 "ec_stripe_loop_calls")
 
     def _perf_inc(self, key: str, n: int = 1) -> None:
         if self.perf is not None and n:
@@ -661,10 +664,15 @@ class ECBackend:
         return sum(cache_of(self.ec).stats()["compiles"].values())
 
     def _count_dispatch(self, h2d: int, d2h: int, programs: int) -> None:
-        """One ecutil call's device dispatch, if it made one: its bytes
-        each way, and the programs built since `programs` (_programs()
-        ahead of the call)."""
-        if h2d and ecutil.batchable(self.ec):
+        """One ecutil call over `h2d` bytes, if any: on the batched path
+        its device dispatch, its bytes each way and the programs built
+        since `programs` (_programs() ahead of the call); otherwise the
+        per-stripe host loop it ran."""
+        if not h2d:
+            return
+        if not ecutil.batchable(self.ec):
+            self._perf_inc("ec_stripe_loop_calls")
+        else:
             self._perf_inc("ec_dispatches")
             self._perf_inc("ec_h2d_bytes", h2d)
             self._perf_inc("ec_d2h_bytes", d2h)
@@ -1361,17 +1369,23 @@ class ECBackend:
                           f"bytes={len(logical)}")
                 self._finish_ec_span(ksp, timings)
             # a dispatch decoded the data shards (chunk_index(0..k-1))
-            # missing from bufs, from the survivors the plugin picks
+            # missing from bufs, from the survivor planes the plugin
+            # picks; the per-stripe path loops over every stripe
             erased = [s for s in map(self.ec.chunk_index, range(self.k))
                       if s not in bufs]
-            if erased and ecutil.batchable(self.ec):
-                inputs = ecutil.decode_inputs(self.ec, erased, bufs)
-                n = len(next(iter(bufs.values())))
-                self._count_dispatch(len(inputs) * n, len(erased) * n,
-                                     programs)
+            n = len(next(iter(bufs.values())))
+            if not ecutil.batchable(self.ec):
+                if n:
+                    self._perf_inc("ec_stripe_loop_calls")
+            elif erased:
+                extents = ecutil.decode_extents(self.ec, erased, bufs)
+                self._count_dispatch(
+                    ecutil.staged_planes(extents) * n
+                    // self.ec.get_sub_chunk_count(),
+                    len(erased) * n, programs)
                 local = getattr(self.ec, "in_local_layer", None)
                 if not rd.for_recovery and local is not None \
-                        and local(inputs):
+                        and local(extents):
                     self._perf_inc("ec_local_decodes")
             size = self._oi_size(rd, oid)
             # highest valid logical byte we can serve from this read

@@ -7,13 +7,15 @@ Three pieces (ref: src/osd/ECUtil.{h,cc}):
 * `encode` / `decode` / `decode_concat` — stripe-batched plugin
   dispatch.  Where the reference loops stripe-by-stripe through the
   plugin (ECUtil.cc:120-159 encode, :9/:47 decode), the TPU build
-  reshapes the whole buffer to (stripes, k, chunk) and runs ONE batched
-  device dispatch (`encode_batch`/`decode_batch`) when the plugin has
-  that API and whole-chunk granularity: `tpu`, and `lrc` whose layers
-  are byte matrix codes (the chunk mapping is honoured; a decode runs
-  on the survivors the plugin's own minimum_to_decode picks).  Plugins
-  without it, or with sub-chunks (isa, jerasure, shec, clay), take the
-  per-stripe loop.
+  reshapes the whole buffer to (stripes, planes, chunk / planes) and
+  runs ONE batched device dispatch (`encode_batch`/`decode_batch`) when
+  the plugin has that API: `tpu`, `lrc` whose layers are byte matrix
+  codes (the chunk mapping is honoured), and `clay` over a byte matrix
+  code, whose chunks are q^t sub-chunk planes.  A decode stages the
+  planes the plugin's own minimum_to_decode names of the survivors it
+  picks: whole chunks, or a clay helper's repair planes.  Plugins
+  without that API (isa, jerasure, shec, and profiles no byte probe can
+  lower) take the per-stripe loop.
 * `HashInfo` — cumulative per-shard crc32c (ECUtil.cc:161 append), the
   xattr-stored integrity metadata ECBackend checks on every sub-read.
 """
@@ -84,16 +86,22 @@ class StripeInfo:
 def batchable(ec) -> bool:
     """Whether encode/decode take the one-dispatch device path: the
     plugin has a batched API (a profile that cannot be lowered sets it
-    to None) and whole-chunk granularity."""
-    return (getattr(ec, "encode_batch", None) is not None
-            and ec.get_sub_chunk_count() == 1)
+    to None)."""
+    return getattr(ec, "encode_batch", None) is not None
 
 
-def decode_inputs(ec, missing, avail) -> list[int]:
-    """The survivors a batched decode of `missing` stages, ascending:
-    the plugin's minimum_to_decode over `avail` (for a matrix code its
-    first k, for lrc the layered walk's, e.g. one local group)."""
-    return sorted(ec.minimum_to_decode(set(missing), set(avail)))
+def decode_extents(ec, missing, avail) -> dict[int, list[tuple[int, int]]]:
+    """The survivors a batched decode of `missing` stages, each with
+    its (sub-chunk offset, count) extents: the plugin's
+    minimum_to_decode over `avail` (for a matrix code its first k
+    whole, for lrc the layered walk's, e.g. one local group, for clay
+    one lost chunk's d helpers' repair planes)."""
+    return ec.minimum_to_decode(set(missing), set(avail))
+
+
+def staged_planes(extents: Mapping[int, list[tuple[int, int]]]) -> int:
+    """Sub-chunk planes per stripe a decode over `extents` stages."""
+    return sum(c for ext in extents.values() for _, c in ext)
 
 
 #: the regions of one timed EC call, in order.  On the batched path:
@@ -183,19 +191,21 @@ def encode(sinfo: StripeInfo, ec, data: bytes,
     if batchable(ec):
         import jax
         st = _Stages(timings)
+        sub = ec.get_sub_chunk_count()
         arr = np.frombuffer(data, dtype=np.uint8).reshape(nstripes, k, cs)
         st.mark("stage")
         _program(ec, st)
         # the one legal host->device crossing of the encode path is
-        # this explicit staging; under CEPH_TPU_JAXGUARD any IMPLICIT
-        # transfer inside the dispatch is an error
-        dev = jax.device_put(arr)
+        # this explicit staging (of the planes: a free view); under
+        # CEPH_TPU_JAXGUARD any IMPLICIT transfer inside the dispatch
+        # is an error
+        dev = jax.device_put(arr.reshape(nstripes, k * sub, cs // sub))
         st.mark("h2d", dev)
         with st.device():
             with jaxguard.guard_transfers():
                 parity_dev = ec.encode_batch(dev)
             st.mark("device", parity_dev)
-        parity = np.asarray(parity_dev)                 # (S, m, cs)
+        parity = np.asarray(parity_dev).reshape(nstripes, m, cs)
         st.mark("d2h")
         out: dict[int, bytes] = {}
         # data chunk i sits at shard chunk_index(i), coding row j at
@@ -210,8 +220,7 @@ def encode(sinfo: StripeInfo, ec, data: bytes,
         st.mark("unstage")
         return out
 
-    # general path: per-stripe plugin encode (handles chunk remapping
-    # and sub-chunk plugins)
+    # general path: per-stripe plugin encode (handles chunk remapping)
     t0 = time.monotonic()
     parts: dict[int, list] = {i: [] for i in want}
     for s in range(nstripes):
@@ -297,7 +306,7 @@ def decode(sinfo: StripeInfo, ec, to_decode: Mapping[int, bytes],
     shard streams (ref: ECUtil.cc:47 decode(map out)).
 
     Batched: a single device dispatch reconstructs every stripe's
-    missing chunks from the survivors decode_inputs picks.  `timings`
+    missing chunks from the survivor planes decode_extents names.  `timings`
     (optional dict) receives a monotonic interval per region of STAGES
     it ran.
     """
@@ -326,10 +335,20 @@ def decode(sinfo: StripeInfo, ec, to_decode: Mapping[int, bytes],
     if batchable(ec):
         import jax
         st = _Stages(timings)
-        decode_index = decode_inputs(ec, missing, avail)
-        stack = np.stack(
-            [np.frombuffer(to_decode[i], dtype=np.uint8)
-             .reshape(nstripes, cs) for i in decode_index], axis=1)
+        sub = ec.get_sub_chunk_count()
+        extents = decode_extents(ec, missing, avail)
+        decode_index = sorted(extents)
+        # (S, planes, cs / sub): each survivor's planes in its extents'
+        # order, survivors ascending
+        stack = np.empty((nstripes, staged_planes(extents), cs // sub),
+                         dtype=np.uint8)
+        row = 0
+        for i in decode_index:
+            planes = np.frombuffer(to_decode[i], dtype=np.uint8) \
+                .reshape(nstripes, sub, cs // sub)
+            for off, count in extents[i]:
+                stack[:, row:row + count] = planes[:, off:off + count]
+                row += count
         st.mark("stage")
         _program(ec, st, decode_index, missing)
         dev = jax.device_put(stack)
@@ -340,7 +359,7 @@ def decode(sinfo: StripeInfo, ec, to_decode: Mapping[int, bytes],
             with jaxguard.guard_transfers():
                 rec_dev = ec.decode_batch(decode_index, missing, dev)
             st.mark("device", rec_dev)
-        rec = np.asarray(rec_dev)
+        rec = np.asarray(rec_dev).reshape(nstripes, len(missing), cs)
         st.mark("d2h")
         for pos, i in enumerate(missing):
             # tobytes() handles the strided view; rec was synced once

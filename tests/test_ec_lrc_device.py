@@ -5,7 +5,8 @@ jerasure reed_sol_van run as one GF(2^8) matmul each, lowered from the
 plugin's own layered arithmetic; their bytes must equal the per-stripe
 host path's, the plain reference's (benchmark/ref/lrc.py) and the
 golden corpus's.  The tpu plugin's bytes and transfers stay as before,
-and a clay pool keeps the per-stripe path.
+a clay profile whose scalar code no byte probe can lower keeps the
+per-stripe path, and a clay pool's dispatches are counted like lrc's.
 """
 import itertools
 import json
@@ -166,7 +167,7 @@ def test_one_lost_data_chunk_decodes_from_its_local_group(name,
         lost = ec.chunk_index(i)
         group = ec.local_layer(lost).chunks_as_set - {lost}
         avail = {s: c for s, c in shards.items() if s != lost}
-        inputs = ecutil.decode_inputs(ec, [lost], avail)
+        inputs = sorted(ecutil.decode_extents(ec, [lost], avail))
         assert inputs == sorted(group) and ec.in_local_layer(inputs)
         for call in range(2):
             timings: dict = {}
@@ -193,16 +194,25 @@ def test_a_profile_with_a_layer_no_byte_probe_can_lower_stays_on_host():
 
 
 def test_clay_keeps_the_per_stripe_path(kernel_calls):
-    ec = registry.factory("clay", {"k": "4", "m": "2"})
-    assert not ecutil.batchable(ec)
+    """Over shec, a scalar code no byte probe can lower, a clay profile
+    has no batched API: encode and decode loop over the stripes in the
+    plugin's own interpreted code, one `kernel` region each.  (Over a
+    byte matrix code clay takes the batched path:
+    tests/test_ec_clay_device.py.)"""
+    ec = registry.factory("clay", {"k": "4", "m": "2",
+                                   "scalar_mds": "shec"})
+    assert ec.encode_batch is None and not ecutil.batchable(ec)
     cs = ec.get_chunk_size(4 * 1024)
     sinfo = ecutil.StripeInfo(4, 4 * cs)
     data = np.random.default_rng(15).bytes(2 * sinfo.stripe_width)
     timings: dict = {}
     shards = ecutil.encode(sinfo, ec, data, timings=timings)
     assert list(timings) == ["kernel"]
+    timings = {}
     assert ecutil.decode_concat(
-        sinfo, ec, {i: c for i, c in shards.items() if i != 1}) == data
+        sinfo, ec, {i: c for i, c in shards.items() if i != 1},
+        timings=timings) == data
+    assert list(timings) == ["kernel"]
     assert kernel_calls == []
 
 
@@ -227,7 +237,8 @@ def test_tpu_bytes_and_transfers_unchanged(kernel_calls):
     assert ecutil.decode_concat(sinfo, ec, avail, timings=timings) == data
     n = STRIPES * CHUNK
     assert kernel_calls == [(4 * n, 2 * n)] and "program" not in timings
-    assert ecutil.decode_inputs(ec, [0, 2], avail) == [1, 3, 4, 5]
+    assert ecutil.decode_extents(ec, [0, 2], avail) == \
+        {s: [(0, 1)] for s in (1, 3, 4, 5)}
 
 
 def test_tpu_with_a_chunk_mapping_on_the_device_path(kernel_calls):
@@ -307,9 +318,11 @@ def test_degraded_read_of_an_lrc_pool_counts_one_local_decode():
 
         # a clay pool's write and read, the read after a decode made
         # outside ECBackend on this thread (as a peering rebuild makes
-        # one): no dispatch is counted
+        # one): only the clay pool's dispatches are counted, the read's
+        # from its d helpers' repair planes where it lost a data shard
         keys = ("ec_dispatches", "ec_h2d_bytes", "ec_d2h_bytes",
-                "ec_local_decodes", "ec_programs_built")
+                "ec_local_decodes", "ec_programs_built",
+                "ec_stripe_loop_calls")
         before = {key: total(key) for key in keys}
         r.mon_command({"prefix": "osd erasure-code-profile set",
                        "name": "clay42",
@@ -319,14 +332,28 @@ def test_degraded_read_of_an_lrc_pool_counts_one_local_decode():
                       erasure_code_profile="clay42")
         c.pump()
         cio = r.open_ioctx("ecc")
-        cio.write_full("c0", objs["o0"])
+        for oid, data in objs.items():
+            cio.write_full(oid, data)
         c.pump()
         lrc = _code("k4m2l3")
         sinfo = ecutil.StripeInfo(4, 4 * 4096)
         shards = ecutil.encode(sinfo, lrc, objs["o0"])
         del shards[0]
         assert ecutil.decode_concat(sinfo, lrc, shards) == objs["o0"]
-        assert cio.read("c0") == objs["o0"]
-        assert {key: total(key) for key in keys} == before
+        for oid, data in objs.items():
+            assert cio.read(oid) == data, oid
+        cmap, cpool = r.objecter.osdmap, r.pool_lookup("ecc")
+        decoding = [oid for oid in objs if {0, 0x7FFFFFFF} & set(
+            cmap.pg_to_up_acting_osds(cmap.object_locator_to_pg(
+                oid, cpool))[2][:4])]
+        assert decoding
+        after = {key: total(key) - before[key] for key in keys}
+        assert after.pop("ec_programs_built") >= 2
+        reads = len(decoding)
+        assert after == {
+            "ec_dispatches": len(objs) + reads,
+            "ec_h2d_bytes": len(objs) * size + reads * 5 * chunks // 2,
+            "ec_d2h_bytes": len(objs) * size // 2 + reads * chunks,
+            "ec_local_decodes": 0, "ec_stripe_loop_calls": 0}
     finally:
         c.shutdown()

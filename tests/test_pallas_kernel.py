@@ -19,7 +19,8 @@ from ceph_tpu.ec.kernels.bitmatmul import (companion_bitmatrix,
     (2, 4, 2, 2048),    # g=2, min tile
     (1, 8, 4, 4096),    # single stripe
     (6, 3, 2, 2112),    # ragged tail (2048 body + 64 xla tail)
-    (4, 8, 4, 1024),    # below min tile -> pure xla fallback
+    (4, 8, 4, 1024),    # below min tile -> whole stripes per grid cell
+    (4, 8, 4, 1000),    # below min tile, ragged lanes -> pure xla fallback
     (4, 2, 1, 6144),    # tiny code, multiple tiles
 ])
 def test_pallas_parity_vs_oracle(s, k, m, n):

@@ -1,7 +1,8 @@
 """The EC kernels of the main path compile for a described v5e chip at
 the widths chip_smoke.py and the benchmark serve (k=8, m=4: 256
 stripes x 128 KiB chunks, and a degraded 4 MiB read's 128 stripes x
-4 KiB), and where the persistent compile cache lives.
+4 KiB; the lrc and clay pools' shapes), and where the persistent
+compile cache lives.
 
 Compiling needs no chip, only the TPU compiler; nothing runs, so this
 says nothing of results or times.  The topology is described inside a
@@ -89,6 +90,21 @@ def test_lrc_kernels_compile(one_chip, r, k, group):
     data = shape(one_chip, (256 if group == 4 else 1, k, 4096), jnp.uint8)
     compiled = bm.gf_matmul_pallas_grouped.lower(
         bgp, data, group=group, tile_n=2048).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("r,k,stripes", [(16, 32, 256), (8, 20, 256),
+                                          (16, 32, 1)])
+def test_clay_kernels_compile(one_chip, r, k, stripes):
+    """The clay k=4 m=2 d=5 pool's batched shapes at its 4 KiB chunks,
+    8 planes of 512 B each: the 16x32 plane encode and the 8x20 repair
+    of one lost chunk from 5 helpers' 4 repair planes, over 256 stripes
+    (a 4 MiB object, 16 stripes to a grid cell) and over one."""
+    group, tile, block = bm.pallas_layout(stripes, k, 512)
+    bgp = shape(one_chip, (8 * r * group, 8 * k * group), jnp.int8)
+    data = shape(one_chip, (stripes, k, 512), jnp.uint8)
+    compiled = bm.gf_matmul_pallas_grouped.lower(
+        bgp, data, group=group, tile_n=tile, block=block).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 

@@ -18,6 +18,7 @@ import threading
 import time
 from typing import Optional
 
+from ..common.crc32c import implementation as crc32c_implementation
 from ..common.heartbeat_map import HeartbeatMap
 from ..common.log import dout
 from ..common.options import global_config
@@ -356,7 +357,8 @@ class OSDDaemon(Dispatcher, MonHunter):
                            "num_pgs": len(self.pgs),
                            "pgs_recovering": self.pgs_recovering(),
                            "hbmap_unhealthy":
-                               self.hbmap.get_unhealthy_workers()}
+                               self.hbmap.get_unhealthy_workers(),
+                           "crc32c": crc32c_implementation()}
         a.register("status", "daemon status", _status)
         a.start()
         self.asok = a

@@ -5,6 +5,7 @@ import time
 import pytest
 
 from ceph_tpu.common.admin_socket import AdminSocket, admin_command
+from ceph_tpu.common.crc32c import implementation as crc32c_implementation
 from ceph_tpu.common.tracked_op import OpTracker
 from ceph_tpu.testing import MiniCluster
 
@@ -63,6 +64,7 @@ def test_osd_admin_socket_end_to_end(tmp_path):
         assert rc == 0 and perf["op"] > 0
         rc, st = admin_command(sock, "status")
         assert rc == 0 and st["whoami"] == 0 and st["num_pgs"] > 0
+        assert st["crc32c"] == crc32c_implementation()
         rc, hist = admin_command(sock, "dump_historic_ops")
         assert rc == 0 and hist["num_ops"] > 0
         ev = [e["event"] for e in hist["ops"][-1]["events"]]

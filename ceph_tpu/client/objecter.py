@@ -31,6 +31,10 @@ from ..osd.types import PG
 _client_ids = itertools.count(4100)
 
 
+#: guards every OpFuture's callback list against its completion
+_callbacks_lock = make_lock("objecter.op_callbacks")
+
+
 class OpFuture:
     """Completion handle for one op."""
 
@@ -40,6 +44,7 @@ class OpFuture:
         self.errno_name: str = ""
         self.data: bytes = b""
         self.attrs: dict = {}
+        self._callbacks: Optional[list] = None
 
     def done(self) -> bool:
         return self._ev.is_set()
@@ -49,12 +54,28 @@ class OpFuture:
             raise TimeoutError("op timed out")
         return self
 
+    def add_done_callback(self, fn) -> None:
+        """Call fn(self) once the op completes, at once if it has.  It
+        runs on the completing thread with the objecter lock held, so
+        it must neither block nor submit ops."""
+        with _callbacks_lock:
+            if not self._ev.is_set():
+                if self._callbacks is None:
+                    self._callbacks = []
+                self._callbacks.append(fn)
+                return
+        fn(self)
+
     def _complete(self, reply: OSDOpReply) -> None:
         self.result = reply.result
         self.errno_name = reply.errno_name
         self.data = reply.data
         self.attrs = reply.attrs
-        self._ev.set()
+        with _callbacks_lock:
+            self._ev.set()
+            callbacks, self._callbacks = self._callbacks, None
+        for fn in callbacks or ():
+            fn(self)
 
 
 class _Op:

@@ -26,6 +26,7 @@ are device-resident; this module is the host-side protocol engine.
 from __future__ import annotations
 
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Callable, Iterable, Optional
 
@@ -546,6 +547,7 @@ class _Write:
     log_entry: Optional[PGLogEntry] = None
     phase: str = "state"      # state -> reads -> commit -> done
     trace: Optional[dict] = None      # blkin context for fan-out spans
+    admitted: float = 0.0     # submit_transaction's monotonic clock
     # ICI-fabric staging (set when the write rode the device mesh)
     fabric_key: Optional[tuple] = None
     chunk_off: int = 0
@@ -644,11 +646,14 @@ class ECBackend:
     #: whose inputs lie in one local layer (lrc); the per-signature
     #: programs the dispatches built (lrc, clay); and the ecutil calls
     #: that ran the per-stripe host loop instead (plugins without the
-    #: batched API)
+    #: batched API); the client writes that read their partly covered
+    #: stripes back before encoding (read-modify-write), with the
+    #: logical bytes those reads asked for
     PERF_KEYS = ("recovery_bytes_read", "recovery_bytes_rebuilt",
                  "ec_dispatches", "ec_h2d_bytes", "ec_d2h_bytes",
                  "ec_local_decodes", "ec_programs_built",
-                 "ec_stripe_loop_calls")
+                 "ec_stripe_loop_calls", "ec_rmw_reads",
+                 "ec_rmw_read_bytes")
 
     def _perf_inc(self, key: str, n: int = 1) -> None:
         if self.perf is not None and n:
@@ -777,7 +782,8 @@ class ECBackend:
             delete = mut.is_delete(muts)
             op = _Write(tid=tid, oid=oid, mutations=list(muts),
                         delete=delete, version=self._next_version(),
-                        on_all_commit=on_all_commit)
+                        on_all_commit=on_all_commit,
+                        admitted=time.monotonic())
             op.trace = trace
             op.log_entry = PGLogEntry(
                 DELETE if delete else MODIFY, oid, op.version,
@@ -840,6 +846,8 @@ class ECBackend:
             return True
         op.reads_needed = plan
         off, length = plan
+        self._perf_inc("ec_rmw_reads")
+        self._perf_inc("ec_rmw_read_bytes", length)
         self.objects_read_and_reconstruct(
             {op.oid: (off, length)},
             lambda results, errors, op=op: self._rmw_reads_done(
@@ -926,6 +934,11 @@ class ECBackend:
 
     def _rmw_reads_done(self, op: _Write, results: dict,
                         errors: dict) -> None:
+        if self.tracer is not None:
+            # the read phase, from admission: the wait behind earlier
+            # ops on the object or the PG, and the sub-reads
+            self.tracer.record_span(child_of(op.trace), "ec_rmw_read",
+                                    op.admitted, time.monotonic())
         with self._lock:
             if errors.get(op.oid):
                 op.read_error = True

@@ -24,12 +24,27 @@ Round 3 adds the librbd depth features (ref: VERDICT r2 #6):
   (ref: src/librbd/ parent/child linkage, cls_rbd children,
   io/CopyupRequest.cc).
 
+An image may keep its data objects in a separate data pool
+(`rbd create --data-pool`, ref: librbd ImageCtx::data_ctx): the header,
+object map, lock and journal stay in the image's own pool, every
+data-object op goes to the data pool, whose self-managed snapids the
+image's snapshots use.  That is how an image lives on an
+erasure-coded pool, which has no omap.  Erasure-coded pools take no
+snapshots here, so an image whose data pool is one refuses
+snap_create with EOPNOTSUPP.
+
+Image IO is asynchronous as librbd's ImageRequestWQ is:
+`aio_write`/`aio_read` stripe the request, take the exclusive lock,
+pre-update the object map, journal, submit one object op per extent
+and return an `AioCompletion`; `write`/`read` wait on it.
+
 API mirrors librbd's Python binding surface: RBD().create/remove/
-list/clone, Image open -> read/write/discard/resize/stat/snap_*/
-diff/du/flatten/close.
+list/clone, Image open -> read/write/aio_read/aio_write/discard/
+resize/stat/snap_*/diff/du/flatten/close.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 
@@ -37,6 +52,10 @@ from ..common.lockdep import make_lock
 import time
 
 from ..client.rados import IoCtx, RadosError
+from ..common.options import global_config
+from ..common.perf_counters import global_perf
+from ..common.tracing import child_of, current_trace, new_trace, \
+    trace_scope
 from ..osdc import StripeLayout, Striper
 
 RBD_DEFAULT_ORDER = 22          # 4 MiB objects (rbd_default_order)
@@ -160,13 +179,84 @@ class ObjectMap:
                 if self.get(o) != self.NONEXISTENT]
 
 
+class AioCompletion:
+    """One image request (ref: librbd::io::AioCompletion): done once
+    every object op it issued is done.  `result` is the request's byte
+    count, or the first failed op's negative errno, named in
+    `errno_name`; a read's bytes are in `data`."""
+
+    def __init__(self, image: "Image", kind: str, name: str):
+        self._ev = threading.Event()
+        self._lock = make_lock("rbd.aio_completion")
+        self._pending = 1           # the submitter's own hold
+        self._image = image
+        self.kind = kind            # "wr" or "rd" (the perf keys)
+        self.result = 0
+        self.errno_name = ""
+        self.data = b""
+        self.buf: bytearray | None = None     # a read's bytes so far
+        self.holes: list = []       # a clone read's parent extents
+        self.t0 = time.monotonic()
+        self.trace = self.span = None
+        if global_config()["blkin_trace_all"]:
+            parent = current_trace()
+            self.trace = child_of(parent) if parent else new_trace()
+            self.span = image._tracer.start_span(self.trace, name)
+
+    def done(self) -> bool:
+        return self._ev.is_set()
+
+    def wait(self, timeout: float = 30.0) -> "AioCompletion":
+        if not self._ev.wait(timeout):
+            raise TimeoutError("image request timed out")
+        return self
+
+    def _scope(self):
+        """The request's span as the ambient parent of its object ops."""
+        return trace_scope(self.trace) if self.trace is not None \
+            else contextlib.nullcontext()
+
+    def _hold(self) -> None:
+        with self._lock:
+            self._pending += 1
+
+    def _fail(self, result: int, errno_name: str) -> None:
+        with self._lock:
+            if self.result >= 0:
+                self.result = result if result < 0 else -5
+                self.errno_name = errno_name or "EIO"
+
+    def _release(self) -> None:
+        with self._lock:
+            self._pending -= 1
+            last = self._pending == 0
+        if last:
+            self._image._request_done(self)
+
+    def _complete(self, result: int) -> None:
+        if self.result >= 0:
+            self.result = result
+        img = self._image
+        if self.span is not None:
+            img._tracer.finish(self.span)
+        if self.result >= 0:
+            img.perf.inc(self.kind)
+            img.perf.inc(self.kind + "_bytes", self.result)
+            img.perf.tinc(self.kind + "_latency",
+                          time.monotonic() - self.t0)
+        self._ev.set()
+
+
 class RBD:
     """Pool-level image operations (ref: librbd::RBD)."""
 
     def create(self, ioctx: IoCtx, name: str, size: int,
                order: int = RBD_DEFAULT_ORDER, stripe_unit: int = 0,
                stripe_count: int = 1,
-               journaling: bool = False) -> None:
+               journaling: bool = False,
+               data_pool: str | None = None) -> None:
+        """`data_pool` names the pool of the image's data objects
+        (`rbd create --data-pool`); None keeps them beside the header."""
         if self._exists(ioctx, name):
             raise RBDError(17, f"image {name!r} exists")
         obj_size = 1 << order
@@ -176,6 +266,8 @@ class RBD:
         layout.validate()
         meta = {"size": size, "order": order, "stripe_unit": su,
                 "stripe_count": stripe_count}
+        if data_pool is not None:
+            meta["data_pool"] = ioctx.rados.pool_lookup(data_pool)
         if journaling:
             # write-ahead mutation journal (ref: librbd journaling
             # feature; consumed by ceph_tpu.rbd.mirror)
@@ -193,7 +285,7 @@ class RBD:
             img._detach_from_parent()
             for objno in range(img._object_span()):
                 try:
-                    ioctx.remove(data_name(name, objno))
+                    img._dio.remove(data_name(name, objno))
                 except RadosError:
                     pass
             img.object_map.remove()
@@ -205,11 +297,12 @@ class RBD:
 
     def clone(self, p_ioctx: IoCtx, p_name: str, p_snap: str,
               c_ioctx: IoCtx, c_name: str,
-              order: int | None = None) -> None:
+              order: int | None = None,
+              data_pool: str | None = None) -> None:
         """Snapshot-backed COW clone (ref: librbd::clone; parent must
         be protected — librbd/internal.cc clone preconditions; child
         records the parent link, parent records the child —
-        cls_rbd children)."""
+        cls_rbd children).  `data_pool` as in create."""
         parent = Image(p_ioctx, p_name)
         try:
             if p_snap not in parent.snaps:
@@ -231,6 +324,8 @@ class RBD:
                                "image": p_name, "snap_name": p_snap,
                                "snap_id": snap["id"],
                                "overlap": overlap}}
+            if data_pool is not None:
+                meta["data_pool"] = c_ioctx.rados.pool_lookup(data_pool)
             c_ioctx.write_full(header_name(c_name),
                                json.dumps(meta).encode())
             parent.meta_children.append(
@@ -282,7 +377,11 @@ class Image:
     librbd::Operations::snap_create -> selfmanaged_snap_create +
     per-image SnapContext on every data-object write): snapids live in
     the image header, the write snapc rides on a private IoCtx, and
-    opening at a snapshot reads each data object at that snapid."""
+    opening at a snapshot reads each data object at that snapid.
+
+    Data objects go through `_dio`, the data pool's IoCtx (ref:
+    ImageCtx::data_ctx); it is `_wio`, the image pool's, when the
+    image has no data pool."""
 
     def __init__(self, ioctx: IoCtx, name: str,
                  snapshot: str | None = None,
@@ -329,8 +428,28 @@ class Image:
         # writes go through a private IoCtx carrying the image snapc
         # (the caller's IoCtx must not inherit it)
         self._wio = IoCtx(ioctx.rados, ioctx.pool_id)
+        self.data_pool: int | None = meta.get("data_pool")
+        self._dio = self._wio if self.data_pool is None else \
+            IoCtx(ioctx.rados, int(self.data_pool))
         self._refresh_snapc()
         self._open = True
+        # librbd's counters, under its names, one logger per image
+        self.perf = global_perf().create(
+            f"librbd-{ioctx._pool_name()}-{name}")
+        have = self.perf.dump()
+        for kind in ("wr", "rd"):
+            for key in (kind, kind + "_bytes"):
+                self.perf.add_u64_counter(key)
+            if kind + "_latency" not in have:
+                self.perf.add_time_avg(kind + "_latency")
+        self._tracer = ioctx.rados.objecter.tracer
+        # write requests in flight; synchronous mutators, flush and
+        # lock release wait for them (ref: librbd blocks and flushes
+        # in-flight IO before snapshots and lock hand-off)
+        self._aio_lock = make_lock(f"rbd.image.{name}.aio")
+        self._aio_writes = 0
+        self._aio_idle = threading.Event()
+        self._aio_idle.set()
         # exclusive-lock state (ref: librbd/exclusive_lock/ManagedLock)
         self._iolock = make_lock(f"rbd.image.{name}")
         self._lock_owned = False
@@ -346,7 +465,6 @@ class Image:
         # and bypass it.  The exclusive lock is the coherence protocol:
         # release flushes + invalidates.
         self._oc = None
-        from ..common.options import global_config
         if global_config()["rbd_cache"] and self._snap_id is None:
             from ..osdc.object_cacher import ObjectCacher
             cfg = global_config()
@@ -362,8 +480,8 @@ class Image:
         resolution Image.read performs per extent)."""
         objno = int(oid)
         try:
-            return self.ioctx.read(data_name(self.name, objno),
-                                   length=length, offset=off)
+            return self._dio.read(data_name(self.name, objno),
+                                  length=length, offset=off)
         except RadosError as ex:
             if ex.errno_name != "ENOENT":
                 raise
@@ -384,13 +502,21 @@ class Image:
         if partial and objno < self._overlap_span() and \
                 self.object_map.get(objno) == ObjectMap.NONEXISTENT:
             self._copyup(objno)
-        self._wio._wait(self._wio.aio_write(
+        self._dio._wait(self._dio.aio_write(
             data_name(self.name, objno), data, offset=off))
         self.object_map.set(objno, ObjectMap.EXISTS, flush=False)
 
+    def _drain(self) -> None:
+        """Wait until no write request of this image is in flight."""
+        if not self.ioctx.rados.objecter.wait_sync(
+                self._aio_idle.is_set, self.ioctx.rados.op_timeout,
+                ev=self._aio_idle):
+            raise TimeoutError("image writes in flight timed out")
+
     def flush(self) -> None:
-        """Flush the write-back cache (ref: rbd_flush): dirty data
-        reaches RADOS and the object map is persisted."""
+        """Flush the write-back cache (ref: rbd_flush): in-flight and
+        dirty data reaches RADOS and the object map is persisted."""
+        self._drain()
         if self._oc is not None:
             with self._iolock:
                 self._oc.flush()
@@ -464,6 +590,7 @@ class Image:
         with self._iolock:
             if not self._lock_owned:
                 return
+            self._drain()
             # the lock is the cache-coherence protocol: dirty data
             # must land and cached state drop BEFORE another client
             # can take the lock (ref: pre-release flush in
@@ -522,11 +649,13 @@ class Image:
         self.parent = None
 
     def _refresh_snapc(self) -> None:
+        """The image's snapc rides on its data-object writes (the
+        snapids are the data pool's)."""
         ids = sorted(s["id"] for s in self.snaps.values())
         if ids:
-            self._wio.set_write_snapc(max(ids), ids)
+            self._dio.set_write_snapc(max(ids), ids)
         else:
-            self._wio.write_snapc = None
+            self._dio.write_snapc = None
 
     # -- metadata ------------------------------------------------------
     def stat(self) -> dict:
@@ -558,7 +687,7 @@ class Image:
         new_span = self._object_span()
         for objno in range(new_span, old_span):
             try:
-                self._wio.remove(data_name(self.name, objno))
+                self._dio.remove(data_name(self.name, objno))
             except RadosError:
                 pass
         # shrinking a clone trims the parent overlap — regrowing must
@@ -575,6 +704,8 @@ class Image:
                 "stripe_unit": self.layout.stripe_unit,
                 "stripe_count": self.layout.stripe_count,
                 "snaps": self.snaps}
+        if self.data_pool is not None:
+            meta["data_pool"] = self.data_pool
         if self.parent is not None:
             meta["parent"] = self.parent
         if self.meta_children:
@@ -590,6 +721,10 @@ class Image:
     def snap_create(self, snap_name: str) -> None:
         self._check_open()
         self._check_writable()
+        pool = self._dio.rados.objecter.osdmap.pools.get(self._dio.pool_id)
+        if pool is not None and pool.is_erasure():
+            raise RBDError(95, "snapshots are not supported on the "
+                               "erasure-coded data pool")
         self._ensure_lock()
         if snap_name in self.snaps:
             raise RBDError(17, f"snapshot {snap_name!r} exists")
@@ -598,7 +733,7 @@ class Image:
         self.flush()
         if self._journal is not None:
             self._journal.append("snap_create", {"name": snap_name})
-        sid = self._wio.selfmanaged_snap_create()
+        sid = self._dio.selfmanaged_snap_create()
         self.snaps[snap_name] = {"id": sid, "size": self.size}
         # fast-diff epoch: freeze the object map beside the snapshot,
         # then EXISTS -> EXISTS_CLEAN on the head map
@@ -620,7 +755,7 @@ class Image:
         if self._journal is not None:
             self._journal.append("snap_remove", {"name": snap_name})
         sid = self.snaps.pop(snap_name)["id"]
-        self._wio.selfmanaged_snap_remove(sid)
+        self._dio.selfmanaged_snap_remove(sid)
         try:
             self._wio.remove(object_map_name(self.name, sid))
         except RadosError:
@@ -693,6 +828,7 @@ class Image:
         self._ensure_lock()
         if snap_name not in self.snaps:
             raise RBDError(2, f"snapshot {snap_name!r} not found")
+        self._drain()
         if self._oc is not None:
             # post-snap dirty data is exactly what rollback discards
             self._oc.invalidate(discard_dirty=True)
@@ -702,13 +838,13 @@ class Image:
         span = max(self._object_span(), self._span_for(snap["size"]))
         # fan the per-object rollbacks out like the write path: one
         # round of aio futures, not span sequential round trips
-        futs = [self._wio.rados.objecter.submit(
-                    self._wio.pool_id, data_name(self.name, objno),
+        futs = [self._dio.rados.objecter.submit(
+                    self._dio.pool_id, data_name(self.name, objno),
                     "rollback",
-                    args=self._wio._margs({"snapid": snap["id"]}))
+                    args=self._dio._margs({"snapid": snap["id"]}))
                 for objno in range(span)]
         for f in futs:
-            self._wio._wait(f)
+            self._dio._wait(f)
         self.size = int(snap["size"])
         # the head object map reverts to the snapshot's frozen map
         try:
@@ -769,19 +905,30 @@ class Image:
             return
         data = parent.read(off, length)
         if data.strip(b"\0"):
-            self._wio.write_full(data_name(self.name, objno), data)
+            self._dio.write_full(data_name(self.name, objno), data)
         self.object_map.set(objno, ObjectMap.EXISTS)
 
     def write(self, offset: int, data: bytes) -> int:
+        """Write and wait (ref: librbd Image::write over aio_write)."""
+        c = self._wait(self.aio_write(offset, data))
+        if c.result < 0:
+            raise RadosError(c.errno_name)
+        return c.result
+
+    def aio_write(self, offset: int, data: bytes) -> AioCompletion:
         """(ref: librbd io/ImageRequest.cc write path: extents through
-        the striper, one object op per extent).  Takes the exclusive
-        lock, copies parent-backed objects up on partial overwrite,
-        and tracks existence in the object map."""
+        the striper, one object op per extent).  Under the image lock:
+        the exclusive lock, the journal event, copy-up of parent-backed
+        objects on partial overwrite, the object map marked EXISTS
+        before any data write is sent (ref: ObjectMap::aio_update's
+        pre-update), and submission; the object ops complete the
+        request as they come back."""
         self._check_open()
         self._check_writable()
         with self._iolock:
             self._ensure_lock()
             length = self._clip(offset, len(data))
+            comp = AioCompletion(self, "wr", "rbd_aio_write")
             if self._journal is not None and length:
                 # write-ahead: the event lands in the journal before
                 # the data objects (ref: librbd journaling ordering)
@@ -796,72 +943,135 @@ class Image:
                                ext.logical_offset - offset
                                + ext.length]
                     self._oc.write(str(ext.objectno), ext.offset, buf)
-                return length
+                comp._complete(length)
+                return comp
             obj_size = 1 << self.order
             over = self._overlap_span()
-            futs = []
-            for ext in Striper.file_to_extents(self.layout, offset,
-                                               length):
+            exts = Striper.file_to_extents(self.layout, offset, length)
+            for ext in exts:
+                state = self.object_map.get(ext.objectno)
                 partial = not (ext.offset == 0
                                and ext.length == obj_size)
                 if partial and ext.objectno < over and \
-                        self.object_map.get(ext.objectno) == \
-                        ObjectMap.NONEXISTENT:
+                        state == ObjectMap.NONEXISTENT:
                     self._copyup(ext.objectno)
-                buf = data[ext.logical_offset - offset:
-                           ext.logical_offset - offset + ext.length]
-                futs.append((ext.objectno, self._wio.aio_write(
-                    data_name(self.name, ext.objectno), buf,
-                    offset=ext.offset)))
-            for objno, f in futs:
-                self._wio._wait(f)
-                self.object_map.set(objno, ObjectMap.EXISTS,
-                                    flush=False)
+                elif state != ObjectMap.EXISTS:
+                    self.object_map.set(ext.objectno, ObjectMap.EXISTS,
+                                        flush=False)
             self.object_map.flush()
-            return length
+            comp.result = length
+            with self._aio_lock:
+                self._aio_writes += 1
+                self._aio_idle.clear()
+            with comp._scope():
+                for ext in exts:
+                    buf = data[ext.logical_offset - offset:
+                               ext.logical_offset - offset + ext.length]
+                    comp._hold()
+                    self._dio.aio_write(
+                        data_name(self.name, ext.objectno), buf,
+                        offset=ext.offset).add_done_callback(
+                            lambda f, c=comp: self._op_done(c, f))
+        comp._release()
+        return comp
 
     def read(self, offset: int, length: int) -> bytes:
+        """Read and wait (ref: librbd Image::read over aio_read)."""
+        c = self._wait(self.aio_read(offset, length))
+        if c.result < 0:
+            raise RadosError(c.errno_name)
+        return c.data
+
+    def aio_read(self, offset: int, length: int) -> AioCompletion:
         """Sparse-aware: missing objects/ranges read as zeros; clone
         reads fall through to the parent snapshot within the overlap
         (ref: io/ImageReadRequest parent read-from)."""
         self._check_open()
         length = self._clip(offset, length)
+        comp = AioCompletion(self, "rd", "rbd_aio_read")
+        comp.buf = bytearray(length)
+        comp.holes = []
         if self._oc is not None and self._snap_id is None:
-            out = bytearray(length)
             for ext in Striper.file_to_extents(self.layout, offset,
                                                length):
                 buf = self._oc.read(str(ext.objectno), ext.offset,
                                     ext.length)
                 base = ext.logical_offset - offset
-                out[base:base + len(buf)] = buf
-            return bytes(out)
-        out = bytearray(length)
-        pend = []
-        for ext in Striper.file_to_extents(self.layout, offset, length):
-            fut = self.ioctx.aio_read(
-                data_name(self.name, ext.objectno),
-                length=ext.length, offset=ext.offset,
-                snapid=self._snap_id)
-            pend.append((ext, fut))
-        obj_size = 1 << self.order
-        for ext, fut in pend:
-            try:
-                buf = self.ioctx._wait(fut).data
-            except RadosError as ex:
-                if ex.errno_name != "ENOENT":
-                    raise
-                buf = b""
-                # whole-object miss on a clone: serve from the parent
-                parent = self._parent()
-                if parent is not None and self.parent is not None:
-                    p_off = ext.objectno * obj_size + ext.offset
-                    p_len = min(ext.length,
-                                self.parent["overlap"] - p_off)
-                    if p_len > 0:
-                        buf = parent.read(p_off, p_len)
+                comp.buf[base:base + len(buf)] = buf
+            comp._release()
+            return comp
+        with comp._scope():
+            for ext in Striper.file_to_extents(self.layout, offset,
+                                               length):
+                comp._hold()
+                self._dio.aio_read(
+                    data_name(self.name, ext.objectno),
+                    length=ext.length, offset=ext.offset,
+                    snapid=self._snap_id).add_done_callback(
+                        lambda f, c=comp, e=ext, b=offset:
+                        self._read_done(c, f, e, b))
+        comp._release()
+        return comp
+
+    def _wait(self, comp: AioCompletion) -> AioCompletion:
+        if not self.ioctx.rados.objecter.wait_sync(
+                comp.done, self.ioctx.rados.op_timeout, ev=comp._ev):
+            raise TimeoutError("image request timed out")
+        return comp
+
+    def _op_done(self, comp: AioCompletion, fut) -> None:
+        """One object op of a write came back (objecter thread)."""
+        if fut.result < 0:
+            comp._fail(fut.result, fut.errno_name)
+        comp._release()
+
+    def _read_done(self, comp: AioCompletion, fut, ext,
+                   offset: int) -> None:
+        """One object read came back (objecter thread): its bytes, a
+        hole (ENOENT), which a clone serves from its parent, or the
+        request's error."""
+        if fut.result >= 0:
             base = ext.logical_offset - offset
-            out[base:base + len(buf)] = buf
-        return bytes(out)
+            comp.buf[base:base + len(fut.data)] = fut.data
+        elif fut.errno_name != "ENOENT":
+            comp._fail(fut.result, fut.errno_name)
+        elif self.parent is not None:
+            comp.holes.append((ext, offset))
+        comp._release()
+
+    def _request_done(self, comp: AioCompletion) -> None:
+        """Every object op of `comp` is done."""
+        if comp.kind == "wr":
+            comp._complete(comp.result)
+            with self._aio_lock:
+                self._aio_writes -= 1
+                if not self._aio_writes:
+                    self._aio_idle.set()
+        elif comp.holes and comp.result >= 0:
+            # the parent's reads are synchronous: never on the
+            # objecter's thread
+            threading.Thread(target=self._fill_holes, args=(comp,),
+                             daemon=True).start()
+        else:
+            comp.data = bytes(comp.buf)
+            comp._complete(len(comp.data))
+
+    def _fill_holes(self, comp: AioCompletion) -> None:
+        """Whole-object misses of a clone read, from the parent."""
+        obj_size = 1 << self.order
+        try:
+            parent = self._parent()
+            for ext, offset in comp.holes:
+                p_off = ext.objectno * obj_size + ext.offset
+                p_len = min(ext.length, self.parent["overlap"] - p_off)
+                if p_len > 0:
+                    base = ext.logical_offset - offset
+                    comp.buf[base:base + p_len] = parent.read(p_off,
+                                                              p_len)
+        except (RadosError, RBDError) as ex:
+            comp._fail(-5, getattr(ex, "errno_name", "EIO"))
+        comp.data = bytes(comp.buf)
+        comp._complete(len(comp.data))
 
     def discard(self, offset: int, length: int) -> None:
         """Zero a range (whole-object removes when covered,
@@ -872,6 +1082,7 @@ class Image:
         self._check_writable()
         with self._iolock:
             self._ensure_lock()
+            self._drain()
             length = self._clip(offset, length)
             if self._oc is not None:
                 # flush dirty state, then drop exactly the discarded
@@ -895,7 +1106,7 @@ class Image:
                 backed = ext.objectno < over
                 if whole and not backed:
                     try:
-                        self._wio.remove(oid)
+                        self._dio.remove(oid)
                     except RadosError:
                         pass
                     self.object_map.set(ext.objectno,
@@ -906,7 +1117,7 @@ class Image:
                         self.object_map.get(ext.objectno) == \
                         ObjectMap.NONEXISTENT:
                     self._copyup(ext.objectno)
-                self._wio.write(oid, b"\0" * ext.length,
+                self._dio.write(oid, b"\0" * ext.length,
                                 offset=ext.offset)
                 self.object_map.set(ext.objectno, ObjectMap.EXISTS,
                                     flush=False)

@@ -108,7 +108,8 @@ def test_every_new_metric_is_declared_for_the_ec_cells():
     per_layer = {m["name"]: m for m in spec["per_layer"]}
     pools = {c["name"] for c in spec["configs"]
              if bench.load_json(ROOT, c["file"])["driver"]
-             in ("ec_cluster", "lrc_cluster", "clay_cluster")}
+             in ("ec_cluster", "lrc_cluster", "clay_cluster",
+                 "rbd_cluster")}
     ec = [w["name"] for w in spec["workloads"] if w["config"] in pools]
     for name in NEW:
         assert per_layer[name]["workloads"] == ec
